@@ -31,6 +31,7 @@ from benchmarks import (
     bench_train_step,
     common,
 )
+from repro.launch.cache import enable_compile_cache
 
 ALL = [
     ("fig1_2_frontier", bench_frontier.main),
@@ -58,6 +59,7 @@ SMOKE = [
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     argv = sys.argv[1:] if argv is None else argv
     json_path = None
     if "--json" in argv:

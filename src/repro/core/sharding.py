@@ -43,7 +43,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 Array = jax.Array
@@ -127,7 +126,7 @@ def shard_fleet_map(fn, sharding: ShardingConfig, *, out_specs=None):
     """``shard_map`` a fleet-batched function over the workers axis.
 
     Every argument and result must carry the fleet axis leading; replicated
-    extras (the grid) should be closed over.  ``check_rep`` is off because
+    extras (the grid) should be closed over.  ``check_vma`` is off because
     the per-worker math is embarrassingly parallel by construction — there
     is nothing cross-shard to verify.
     """
@@ -136,7 +135,7 @@ def shard_fleet_map(fn, sharding: ShardingConfig, *, out_specs=None):
     )
 
     def wrapped(*args):
-        return shard_map(
+        return jax.shard_map(
             fn,
             mesh=sharding.mesh,
             in_specs=tuple(spec_of(a) for a in args),
@@ -145,7 +144,7 @@ def shard_fleet_map(fn, sharding: ShardingConfig, *, out_specs=None):
                 if out_specs is None
                 else out_specs
             ),
-            check_rep=False,
+            check_vma=False,
         )(*args)
 
     return wrapped

@@ -247,7 +247,6 @@ def fit_hyperprior_sharded(
     hyperprior.  K not divisible by the shard count is padded with
     mask-0 dummy workers, which contribute nothing to any statistic.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     k = jax.tree_util.tree_leaves(fleet)[0].shape[0]
@@ -269,12 +268,12 @@ def fit_hyperprior_sharded(
     out_spec = jax.tree_util.tree_map(
         lambda _: P(), jax.eval_shape(_fit_hyperprior_body, fleet, m)
     )
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=sharding.mesh,
         in_specs=(spec_of(fleet), P(sharding.axis)),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )(fleet, m)
 
 

@@ -1,8 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels run in ``interpret=True`` mode — the
-kernel body executes eagerly against the oracle semantics; on TPU they lower
-to real Mosaic kernels.  The switch is automatic via ``jax.default_backend``.
+On a TPU backend the kernels lower to Mosaic kernels; the posterior-grid
+kernel lays every per-worker array out as (K, 1, X) with the worker axis
+squeezed from its blocks (see ``posterior_grid``).  On any other backend
+they run with ``interpret=True``, which emulates the kernel body and exists
+for the CPU tests only.  The switch follows ``jax.default_backend``.
 """
 from __future__ import annotations
 

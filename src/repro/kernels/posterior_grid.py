@@ -24,14 +24,24 @@ multiply-accumulate passes (the pure-jnp oracle
 interpret-mode parity is tight).
 
 TPU mapping:
-  * fleet axis      -> leading pallas grid dimension (one program row per worker)
-  * grid axis       -> lanes (BG = 128-aligned blocks)
-  * observation axis -> streamed VMEM blocks (BN), reduced sequentially via
-    the revisiting-output accumulation pattern: pallas grid = (K, G/BG, N/BN);
-    both output blocks for a given (k, g-tile) stay resident in VMEM while
-    the inner n-loop accumulates into them.
+  * fleet axis      -> leading pallas grid dimension (one program row per
+    worker).  Every per-worker array is laid out (K, 1, X) and its blocks
+    squeeze the worker axis, (None, 1, X-block), so a block's trailing two
+    dims span the whole unit axis and a lane block: the (8, 128) tiling
+    rule holds for any K.
+  * grid axis       -> lanes (BG = 128-aligned blocks, or all of G when
+    G <= block_g); the shared (1, G) grid row is blocked (1, BG)
+  * observation axis -> streamed VMEM blocks (BN >= 128), reduced
+    sequentially via the revisiting-output accumulation pattern: pallas
+    grid = (K, G/BG, N/BN); both output blocks for a given (k, g-tile) stay
+    resident in VMEM while the inner n-loop accumulates into them.
   * per-worker scalars (mu, lam, alpha, beta, priors, sum_logf) ride in a
     packed (1, 16) parameter row mapped to every block of worker k.
+
+On the TPU the kernel lowers to Mosaic (``interpret=False``).  Interpret
+mode is for the CPU tests only: it emulates the kernel body and accepts
+block shapes the TPU compiler refuses, so ``tests/test_tpu_compile.py``
+compiles the kernel for a described v5e chip as well.
 """
 from __future__ import annotations
 
@@ -169,33 +179,36 @@ def posterior_grid_fleet_pallas(
     n_gb = grid_p.shape[0] // bg
     n_nb = t_p.shape[1] // bn
 
+    # (K, 1, X) arrays with the worker axis squeezed from every block (see
+    # "TPU mapping" above): the only layout the TPU compiler accepts for all K.
+    row = lambda x: x[:, None, :]
+    per_worker = lambda width, col: pl.BlockSpec(
+        (None, 1, width), lambda ki, gi, ni: (ki, 0, col(gi, ni))
+    )
+    obs = per_worker(bn, lambda gi, ni: ni)
+    cell = per_worker(bg, lambda gi, ni: gi)
+    out_shape = jax.ShapeDtypeStruct((k, 1, grid_p.shape[0]), jnp.float32)
     out_a, out_b = pl.pallas_call(
         _fleet_kernel,
         grid=(k, n_gb, n_nb),
         in_specs=[
-            pl.BlockSpec((1, _PARAM_WIDTH), lambda ki, gi, ni: (ki, 0)),  # params
+            per_worker(_PARAM_WIDTH, lambda gi, ni: 0),  # params
             pl.BlockSpec((1, bg), lambda ki, gi, ni: (0, gi)),  # grid
-            pl.BlockSpec((1, bn), lambda ki, gi, ni: (ki, ni)),  # t
-            pl.BlockSpec((1, bn), lambda ki, gi, ni: (ki, ni)),  # f
-            pl.BlockSpec((1, bn), lambda ki, gi, ni: (ki, ni)),  # mask
+            obs,  # t
+            obs,  # f
+            obs,  # mask
         ],
-        out_specs=[
-            pl.BlockSpec((1, bg), lambda ki, gi, ni: (ki, gi)),
-            pl.BlockSpec((1, bg), lambda ki, gi, ni: (ki, gi)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((k, grid_p.shape[0]), jnp.float32),
-            jax.ShapeDtypeStruct((k, grid_p.shape[0]), jnp.float32),
-        ],
+        out_specs=[cell, cell],
+        out_shape=[out_shape, out_shape],
         interpret=interpret,
     )(
-        params,
+        row(params),
         grid_p[None, :],
-        t_p,
-        f_p,
-        mask_p,
+        row(t_p),
+        row(f_p),
+        row(mask_p),
     )
-    return jnp.stack([out_a[:, :g_n], out_b[:, :g_n]], axis=1)
+    return jnp.concatenate([out_a[..., :g_n], out_b[..., :g_n]], axis=1)
 
 
 @functools.partial(

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch, reduced
+from repro.launch.cache import enable_compile_cache
 from repro.models import model_zoo
 from repro.models.layers import ApplyCtx
 from repro.train import serve_step
@@ -158,6 +159,7 @@ def _partitioned_serving(cfg, args) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--batch", type=int, default=4)

@@ -14,10 +14,12 @@ import numpy as np
 from repro.configs import RunConfig, get_arch, get_shape, reduced
 from repro.configs.base import ShapeConfig
 from repro.distributed.simulated_cluster import SimulatedCluster, WorkerSpec
+from repro.launch.cache import enable_compile_cache
 from repro.train.trainer import Trainer
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--steps", type=int, default=50)

@@ -205,8 +205,6 @@ def moe_ffn(
         y, probs = _moe_local(cfg, params, x_flat)
         y = y.reshape(b, t, d)
     else:
-        from jax.experimental.shard_map import shard_map
-
         n_model = mi.mesh.shape[mi.model_axis] if mi.model_axis else 1
         ep = n_data > 1 and cfg.num_experts % n_data == 0
         tp_f = (
@@ -239,12 +237,12 @@ def moe_ffn(
             y, probs = fn(router_w, wi, wg, wo, xf)
             return y.reshape(xb.shape), probs
 
-        y, probs = shard_map(
+        y, probs = jax.shard_map(
             wrapped,
             mesh=mi.mesh,
             in_specs=(*w_specs, x_spec),
             out_specs=(x_spec, probs_spec),
-            check_rep=False,
+            check_vma=False,
         )(params["router"], params["wi"], params["wg"], params["wo"], x)
 
     if cfg.moe_residual:

@@ -189,3 +189,37 @@ def test_cache_rules_prefer_kv_heads_then_seq():
     spec = spec_for((128, 32768, 4, 64), ("batch", "seq", "kv_heads", "head_dim"),
                     FakeMesh(), rules)
     assert spec[1] == "model" and spec[2] is None
+
+
+def _record_cache_config(monkeypatch):
+    """Stand in for ``jax.config`` inside the cache helper: the tests never
+    turn the persistent cache on, they only see what the helper would set."""
+    import types
+
+    from repro.launch import cache
+
+    calls = []
+    stub = types.SimpleNamespace(
+        config=types.SimpleNamespace(update=lambda *a: calls.append(a))
+    )
+    monkeypatch.setattr(cache, "jax", stub)
+    return cache, calls
+
+
+def test_compile_cache_left_to_jax_when_env_names_it(monkeypatch, tmp_path):
+    cache, calls = _record_cache_config(monkeypatch)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert cache.enable_compile_cache() == str(tmp_path)
+    assert calls == []
+
+
+def test_compile_cache_defaults_to_fixed_repo_dir(monkeypatch):
+    import pathlib
+
+    cache, calls = _record_cache_config(monkeypatch)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    want = str(root / ".jax_cache")
+    assert cache.enable_compile_cache() == want
+    assert cache.enable_compile_cache() == want  # fixed: never per-process
+    assert calls == [("jax_compilation_cache_dir", want)] * 2

