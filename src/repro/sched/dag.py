@@ -64,7 +64,6 @@ from .scheduler import (
     SchedulerConfig,
     Telemetry,
     advance_fleet,
-    proposal_floor,
     solve_fractions,
     unit_params_from_gibbs,
 )
@@ -567,11 +566,10 @@ def _joint_refine(
         logits = jnp.where(live > 0, logits, -1e9)
     f = jax.nn.softmax(logits, axis=-1)
     # Same per-worker floor discipline as solve_fractions, rows renormalized.
-    floor = proposal_floor(config.min_fraction, f.shape[-1], live)
     if live is None:
-        f = jnp.maximum(f, floor)
+        f = jnp.maximum(f, config.min_fraction)
     else:
-        f = jnp.where(live > 0, jnp.maximum(f, floor), 0.0)
+        f = jnp.where(live > 0, jnp.maximum(f, config.min_fraction), 0.0)
     return f / jnp.sum(f, axis=-1, keepdims=True)
 
 

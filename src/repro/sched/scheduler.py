@@ -322,21 +322,6 @@ def _equalizing_fractions(
     return f / jnp.maximum(jnp.sum(f), 1e-30)
 
 
-def proposal_floor(min_fraction: float, k: int, live: Optional[Array] = None):
-    """Per-worker proposal floor: ``min_fraction``, capped at half the
-    uniform share of the live fleet.
-
-    A flat floor above 1/(2K) lifts every worker of a large fleet to the same
-    value and so forces the uniform split: at K = 10^4 the default 5e-3 is
-    50x the uniform share.  Fleets of up to 1 / (2 min_fraction) workers
-    (100 at the default) keep ``min_fraction`` exactly.
-    """
-    if live is None:
-        return min(min_fraction, 0.5 / k)
-    n_live = jnp.maximum(jnp.sum(live, axis=-1, keepdims=True), 1.0)
-    return jnp.minimum(min_fraction, 0.5 / n_live)
-
-
 @functools.partial(
     jax.jit, static_argnames=("objective", "steps", "num_points", "min_fraction")
 )
@@ -361,8 +346,7 @@ def solve_fractions(
     neither the equalizing init nor the objective ever consults their parked
     posteriors.
 
-    Proposals are floored at ``min_fraction`` per worker (capped at half the
-    uniform share, see ``proposal_floor``): SPMD quantization
+    Proposals are floored at ``min_fraction`` per worker: SPMD quantization
     gives every live worker at least one microbatch anyway, and telemetry at
     f -> 0 carries unbounded weight f^(alpha-2beta) in the Normal-Gamma
     update — one near-zero assignment could poison a worker's posterior
@@ -427,11 +411,10 @@ def solve_fractions(
 
     # Safeguard: descent may only improve on the analytic candidates.
     cands = jnp.stack([f_ref, f_eq, f_uni])  # (3, K)
-    floor = proposal_floor(min_fraction, k, live)
     if live is None:
-        cands = jnp.maximum(cands, floor)
+        cands = jnp.maximum(cands, min_fraction)
     else:
-        cands = jnp.where(live > 0, jnp.maximum(cands, floor), 0.0)
+        cands = jnp.where(live > 0, jnp.maximum(cands, min_fraction), 0.0)
     cands = cands / jnp.sum(cands, axis=-1, keepdims=True)
     scores = jax.vmap(
         lambda f: evaluate(
