@@ -158,7 +158,7 @@ def _fleet_case(
         def one_cycle(loop, fracs, step):
             for _ in range(_RING):
                 loop.push(fracs, step())
-            return loop.tick().ll
+            return loop.tick().drift
 
         a_us, b_us = time_pair_min(
             lambda: one_cycle(dense, fracs, step),

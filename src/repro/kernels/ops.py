@@ -128,9 +128,10 @@ def posterior_grid_fleet(
         per_k(alpha_prior.a), per_k(alpha_prior.b),
         per_k(beta_prior.a), per_k(beta_prior.b),
     )
-    launch = lambda *a: posterior_grid_fleet_pallas(
-        grid, *a, interpret=_interpret()
-    )
+    def launch(*a):
+        with jax.named_scope("posterior_grid"):
+            return posterior_grid_fleet_pallas(grid, *a, interpret=_interpret())
+
     if sharding is None:
         return launch(*args)
 

@@ -49,6 +49,7 @@ from typing import NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import gibbs
 from repro.core.frontier import (
     UnitParams,
@@ -360,7 +361,13 @@ class DagProposeStats(NamedTuple):
 
 @functools.partial(jax.jit, static_argnames=("config", "dag"))
 def init_dag(config: SchedulerConfig, dag: WorkflowDAG, key: Array) -> DagState:
-    """Fresh beliefs for every stage's fleet."""
+    """Fresh beliefs for every stage's fleet.
+
+    The DAG's programs open no host span, so this also starts the
+    ``repro.obs`` trace tally (``obs.hook``): ``observe_dag`` and
+    ``propose_dag`` are then counted from their first trace.
+    """
+    obs.hook()
     s, k = dag.num_stages, dag.num_workers
     key, sub = jax.random.split(key)
     keys = jax.random.split(sub, s * k)
@@ -409,13 +416,14 @@ def observe_dag(
             else jnp.broadcast_to(mask, telemetry.times.shape) * lv
         )
     fold = gibbs.fold_stage_axis
-    fleet, ll = advance_fleet(
-        fold(state.gibbs),
-        fold(telemetry.times),
-        fold(telemetry.fracs),
-        config,
-        mask=None if mask is None else fold(jnp.broadcast_to(mask, telemetry.times.shape)),
-    )
+    with jax.named_scope("gibbs_advance"):
+        fleet, ll = advance_fleet(
+            fold(state.gibbs),
+            fold(telemetry.times),
+            fold(telemetry.fracs),
+            config,
+            mask=None if mask is None else fold(jnp.broadcast_to(mask, telemetry.times.shape)),
+        )
     return (
         state._replace(gibbs=gibbs.unfold_stage_axis(fleet, s), step=state.step + 1),
         ll.reshape(telemetry.times.shape[:2]),
@@ -644,6 +652,7 @@ def propose_dag(
         min_fraction=config.min_fraction,
     )
 
+    @jax.named_scope("stage_solve")
     def vsolve(p, objective, live_rows=None, **overrides):
         """One vmapped solve across a leading stage axis."""
         names = tuple(k for k, v in overrides.items() if v is not None)
@@ -755,14 +764,15 @@ def propose_dag(
         if stochastic:
             # Joint end-to-end refinement: keep it only if the composed
             # objective actually improves.
-            refined = _joint_refine(dag, fracs, params, obj, config, live)
-            sc_base = _dag_objective_score(
-                dag, fracs, params, obj, config.num_points
-            )
-            sc_ref = _dag_objective_score(
-                dag, refined, params, obj, config.num_points
-            )
-            fracs = jnp.where(sc_ref < sc_base, refined, fracs)
+            with jax.named_scope("joint_refine"):
+                refined = _joint_refine(dag, fracs, params, obj, config, live)
+                sc_base = _dag_objective_score(
+                    dag, fracs, params, obj, config.num_points
+                )
+                sc_ref = _dag_objective_score(
+                    dag, refined, params, obj, config.num_points
+                )
+                fracs = jnp.where(sc_ref < sc_base, refined, fracs)
 
     stats = dag_stats(dag, fracs, params, stats_obj, num_points=config.num_points)
     return fracs, stats

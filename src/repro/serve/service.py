@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.frontier import UnitParams
 from repro.sched.objectives import Objective
 from repro.sched.scheduler import (
@@ -130,12 +131,13 @@ class ServeState(NamedTuple):
 
 
 class TickInfo(NamedTuple):
-    """Per-tick observability (small, cheap to host-sync)."""
+    """Per-tick observability (scalars, cheap to host-sync)."""
 
-    ll: Array  # (K,) per-worker log-likelihood of the drained batch
     proposed: Array  # bool: did this tick re-solve the split?
     drift: Array  # float32 gate statistic (KL drift or max surprise)
     drained: Array  # int32 observations consumed from the ring
+    fired: Array  # bool: the drift gate fired (else a propose is the
+    # staleness cap's)
 
 
 def posterior_drift(ref: UnitParams, cur: UnitParams) -> Array:
@@ -213,15 +215,16 @@ def solve_published(
     asynchronous — the call returns as soon as the program is enqueued) and
     publish on completion.
     """
-    fr, st = solve_fractions(
-        cur,
-        objective=config.sched.objective,
-        steps=config.sched.opt_steps,
-        lr=config.sched.opt_lr,
-        num_points=config.sched.num_points,
-        min_fraction=config.sched.min_fraction,
-        live=live,
-    )
+    with jax.named_scope("solve"):
+        fr, st = solve_fractions(
+            cur,
+            objective=config.sched.objective,
+            steps=config.sched.opt_steps,
+            lr=config.sched.opt_lr,
+            num_points=config.sched.num_points,
+            min_fraction=config.sched.min_fraction,
+            live=live,
+        )
     return fr.astype(jnp.float32), ProposeStats(
         e_t=st.e_t.astype(jnp.float32),
         var=st.var.astype(jnp.float32),
@@ -269,7 +272,7 @@ def _tick_body(
         )
 
     def advance(sched_state):
-        fleet, ll = advance_fleet(
+        fleet, _ = advance_fleet(
             sched_state.gibbs,
             batch.times,
             batch.fracs,
@@ -277,15 +280,12 @@ def _tick_body(
             mask=batch.mask,
             active_idx=active_idx,
         )
-        return (
-            sched_state._replace(gibbs=fleet, step=sched_state.step + 1),
-            ll.astype(jnp.float32),
+        return sched_state._replace(gibbs=fleet, step=sched_state.step + 1)
+
+    with jax.named_scope("gibbs_advance"):
+        new_sched = jax.lax.cond(
+            has_data, advance, lambda s: s, state.sched
         )
-
-    def hold(sched_state):
-        return sched_state, jnp.zeros_like(sched_state.ewma_ll)
-
-    new_sched, ll = jax.lax.cond(has_data, advance, hold, state.sched)
 
     # -- gate statistic (static branch: config is jit-static) ---------------
     if config.sched.hierarchical:
@@ -333,22 +333,21 @@ def _tick_body(
 
     staleness = state.staleness + has_data.astype(jnp.int32)
     # -- gate decision (static branch on the configured threshold) ----------
-    if config.drift_threshold is None:
-        fire, gate = gate_update(
-            state.gate,
-            drift,
-            z=config.gate_z,
-            warmup=config.gate_warmup,
-            decay=config.gate_decay,
-            update=has_data,
-        )
-        should = has_data & (fire | (staleness >= config.max_staleness))
-    else:
-        gate = state.gate  # fixed threshold: the baseline is never touched
-        should = has_data & (
-            (drift > config.drift_threshold)
-            | (staleness >= config.max_staleness)
-        )
+    with jax.named_scope("drift_gate"):
+        if config.drift_threshold is None:
+            fire, gate = gate_update(
+                state.gate,
+                drift,
+                z=config.gate_z,
+                warmup=config.gate_warmup,
+                decay=config.gate_decay,
+                update=has_data,
+            )
+        else:
+            gate = state.gate  # fixed threshold: the baseline is never touched
+            fire = drift > config.drift_threshold
+        fired = has_data & fire
+        should = fired | (has_data & (staleness >= config.max_staleness))
 
     if config.async_propose:
         # The solve leaves the tick: only the bookkeeping happens here
@@ -388,7 +387,7 @@ def _tick_body(
         refresh_age=refresh_age,
     )
     return new_state, TickInfo(
-        ll=ll, proposed=should, drift=drift, drained=drained
+        proposed=should, drift=drift, drained=drained, fired=fired
     ), cur
 
 
@@ -434,6 +433,11 @@ class ServiceLoop:
 
     ``state`` is the checkpointable pytree — hand it to
     ``CheckpointManager.save`` and assign it back after restore.
+
+    Every push, tick and publication is a ``repro.obs`` span (``serve.*``,
+    ``docs/serving.md`` "Observability") carrying ``beat``, the number of
+    ticks before it: the pushes a tick drains share its beat, and so does the
+    publication of the split it solves.
     """
 
     def __init__(
@@ -459,17 +463,21 @@ class ServiceLoop:
         self._active = 0
         self._version = 0
         self._pending: Optional[Tuple[Array, ProposeStats]] = None
+        self._pending_beat = 0  # the beat whose tick dispatched _pending
+        self._beat = 0  # ticks so far
+        self._host = dict(rows_drained=0, proposes_gate=0, proposes_stale=0)
 
     # -- ingestion (producer side) -----------------------------------------
     def push(self, fracs, times, valid=None) -> None:
         """Buffer one telemetry row; returns immediately (device-async)."""
-        ring = self._push(
-            self.state.ring,
-            jnp.asarray(fracs, jnp.float32),
-            jnp.asarray(times, jnp.float32),
-            None if valid is None else jnp.asarray(valid, jnp.float32),
-        )
-        self.state = self.state._replace(ring=ring)
+        with obs.span("serve.push", beat=self._beat):
+            ring = self._push(
+                self.state.ring,
+                jnp.asarray(fracs, jnp.float32),
+                jnp.asarray(times, jnp.float32),
+                None if valid is None else jnp.asarray(valid, jnp.float32),
+            )
+            self.state = self.state._replace(ring=ring)
 
     # -- the service beat (estimator side) ---------------------------------
     def tick(self) -> TickInfo:
@@ -481,19 +489,41 @@ class ServiceLoop:
         publishing into the inactive buffer and bumping ``version`` exactly
         as the synchronous path does.  A solve already in flight suppresses
         re-dispatch; the gate refires on a later beat if drift persists.
+
+        The tick waits on the device once, for its three flags.
         """
-        if self.config.async_propose:
-            self.poll()
-            self.state, info, cur = tick_with_params(self.state, self.config)
-            if bool(info.proposed) and self._pending is None:
-                self._pending = solve_published(
-                    cur, self.config, self.state.sched.live
+        beat = self._beat
+        with obs.span("serve.tick", beat=beat) as sp:
+            if self.config.async_propose:
+                self.poll()
+                self.state, info, cur = tick_with_params(
+                    self.state, self.config
                 )
-            return info
-        self.state, info = tick(self.state, self.config)
-        if bool(info.proposed):  # host-syncs the tiny flag, not the fleet
-            self._publish(self.state.fractions)
+            else:
+                self.state, info = tick(self.state, self.config)
+            with obs.span("serve.wait"):  # the fleet stays on the device
+                proposed, fired, drained = jax.device_get(
+                    (info.proposed, info.fired, info.drained)
+                )
+            proposed, fired, drained = bool(proposed), bool(fired), int(drained)
+            sp.set(proposed=proposed, fired=fired, drained=drained)
+            self._tally(proposed, fired, drained)
+            if proposed and not self.config.async_propose:
+                self._publish(self.state.fractions, beat)
+            elif proposed and self._pending is None:
+                with obs.span("serve.dispatch_solve"):
+                    self._pending = solve_published(
+                        cur, self.config, self.state.sched.live
+                    )
+                self._pending_beat = beat
+        self._beat = beat + 1
         return info
+
+    def _tally(self, proposed: bool, fired: bool, drained: int) -> None:
+        """Add one tick to this loop's host tallies (``counters()``)."""
+        self._host["rows_drained"] += drained
+        if proposed:
+            self._host["proposes_gate" if fired else "proposes_stale"] += 1
 
     def poll(self) -> bool:
         """Publish a completed async solve, if any; never blocks.
@@ -504,19 +534,21 @@ class ServiceLoop:
         """
         if self._pending is None:
             return False
-        fr, st = self._pending
-        if not fr.is_ready():
-            return False
-        self._pending = None
-        self.state = self.state._replace(fractions=fr, stats=st)
-        self._publish(fr)
+        with obs.span("serve.poll", beat=self._beat):
+            fr, st = self._pending
+            if not fr.is_ready():
+                return False
+            self._pending = None
+            self.state = self.state._replace(fractions=fr, stats=st)
+            self._publish(fr, self._pending_beat)
         return True
 
-    def _publish(self, fractions) -> None:
-        inactive = 1 - self._active
-        self._slots[inactive][:] = np.asarray(fractions)
-        self._active = inactive  # atomic flip: readers see old or new
-        self._version += 1
+    def _publish(self, fractions, beat: int) -> None:
+        with obs.span("serve.publish", beat=beat):
+            inactive = 1 - self._active
+            self._slots[inactive][:] = np.asarray(fractions)
+            self._active = inactive  # atomic flip: readers see old or new
+            self._version += 1
 
     # -- publication (reader side; never blocks) ---------------------------
     def fractions(self) -> np.ndarray:
@@ -530,12 +562,20 @@ class ServiceLoop:
 
     # -- observability ------------------------------------------------------
     def counters(self) -> dict:
-        """Lifetime drain/propose/drop counters (host-syncs four scalars)."""
+        """Lifetime drain/propose/drop counters, read in one transfer, and
+        this loop's host tallies: ``rows_drained`` and the proposes of the
+        gate and of the staleness cap (they sum to ``proposes`` for a loop
+        started from a fresh state)."""
+        s = self.state
+        drains, proposes, dropped, pushes = jax.device_get(
+            (s.n_drains, s.n_proposes, s.ring.dropped, s.ring.total)
+        )
         return {
-            "drains": int(self.state.n_drains),
-            "proposes": int(self.state.n_proposes),
-            "dropped": int(self.state.ring.dropped),
-            "pushes": int(self.state.ring.total),
+            "drains": int(drains),
+            "proposes": int(proposes),
+            "dropped": int(dropped),
+            "pushes": int(pushes),
+            **self._host,
         }
 
     @property

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import sched, serve
+from repro import obs, sched, serve
 from repro.core import gibbs
 
 N_ITERS, GRID = 3, 64
@@ -215,6 +215,61 @@ def test_service_loop_learns_split_end_to_end(host_staging):
     c = loop.counters()
     assert c["drains"] == 10 and 1 <= c["proposes"] <= c["drains"]
     assert c["pushes"] == 10 * loop.config.capacity and c["dropped"] == 0
+
+
+# -------------------------------------------------------------- observability
+def test_host_tallies_split_proposes_and_count_drained_rows():
+    """The gate's and the staleness cap's proposes sum to the device count,
+    and every pushed row is drained."""
+    rng = np.random.default_rng(5)
+    mu = np.array([2.0, 4.0, 6.0])
+    loop = serve.ServiceLoop(3, config=_steady_cfg(max_staleness=2), seed=1)
+    _push_rounds(loop, mu, 6, rng)
+    _push_rounds(loop, mu * np.array([4.0, 1.0, 1.0]), 2, rng)
+    c = loop.counters()
+    assert c["proposes_gate"] + c["proposes_stale"] == c["proposes"]
+    assert c["proposes_gate"] >= 1 and c["proposes_stale"] >= 1
+    assert c["rows_drained"] == c["pushes"] == 8 * loop.config.capacity
+    assert c["drains"] == 8 and c["dropped"] == 0
+
+
+def test_tick_records_carry_flags_and_share_a_beat_with_their_pushes():
+    rng = np.random.default_rng(6)
+    mu = np.array([2.0, 4.0])
+    loop = serve.ServiceLoop(2, config=_steady_cfg(max_staleness=2), seed=2)
+    obs.reset()
+    infos = _push_rounds(loop, mu, 5, rng)
+    spans = obs.snapshot()["spans"]
+    ticks = [s for s in spans if s["name"] == "serve.tick"]
+    assert [t["beat"] for t in ticks] == list(range(5))
+    for t, info in zip(ticks, infos):
+        assert t["attrs"] == {
+            "proposed": bool(info.proposed),
+            "fired": bool(info.fired),
+            "drained": int(info.drained),
+        }
+        mine = [s for s in spans if s["beat"] == t["beat"]]
+        pushes = [s for s in mine if s["name"] == "serve.push"]
+        assert len(pushes) == t["attrs"]["drained"] == loop.config.capacity
+        assert all(p["start_ns"] < t["start_ns"] for p in pushes)
+        children = sorted(s["name"] for s in mine if s["parent"] == t["id"])
+        want = ["serve.publish", "serve.wait"] if info.proposed else ["serve.wait"]
+        assert children == want
+    assert any(t["attrs"]["proposed"] for t in ticks)
+    assert not all(t["attrs"]["proposed"] for t in ticks)
+
+
+def test_ten_ticks_trace_the_tick_once():
+    rng = np.random.default_rng(7)
+    mu = np.array([2.0, 4.0])
+    # a ring size no other test uses, so the first tick here traces
+    loop = serve.ServiceLoop(2, config=_steady_cfg(capacity=11), seed=0)
+    before = obs.snapshot()["traces"]
+    _push_rounds(loop, mu, 10, rng)
+    after = obs.snapshot()["traces"]
+    assert after.get("tick", 0) - before.get("tick", 0) == 1
+    assert after.get("push", 0) - before.get("push", 0) <= 1
+    assert loop.counters()["drains"] == 10
 
 
 # ------------------------------------------------------------ donation/memory

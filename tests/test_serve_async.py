@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import sched, serve
+from repro import obs, sched, serve
 
 SCHED = sched.SchedulerConfig(n_iters=2, grid_size=32, num_points=64,
                               opt_steps=10)
@@ -61,6 +61,26 @@ def test_async_tick_does_not_publish_until_poll():
     assert np.isfinite(float(loop.state.stats.e_t))
     # drained once more with nothing new: no spurious publish
     assert loop.poll() is False
+
+
+def test_async_publication_shares_the_dispatching_tick_beat():
+    loop = serve.ServiceLoop(3, config=_config(async_propose=True), seed=0)
+    obs.reset()
+    _feed(loop, rounds=1)
+    jax.block_until_ready(loop._pending[0])
+    assert loop.poll() is True
+    spans = obs.snapshot()["spans"]
+    named = lambda n: [s for s in spans if s["name"] == n]
+    (tick,), (dispatch,), (poll,), (pub,) = map(
+        named, ("serve.tick", "serve.dispatch_solve", "serve.poll",
+                "serve.publish"))
+    assert dispatch["parent"] == tick["id"] and pub["parent"] == poll["id"]
+    # the publication follows the rows it absorbed: the dispatching beat
+    assert tick["beat"] == dispatch["beat"] == pub["beat"] == 0
+    assert poll["beat"] == 1
+    c = loop.counters()
+    assert c["proposes_gate"] + c["proposes_stale"] == c["proposes"] == 1
+    assert c["rows_drained"] == c["pushes"] == 8
 
 
 def test_async_pending_solve_suppresses_redispatch():
