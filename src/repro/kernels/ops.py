@@ -1,10 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 On a TPU backend the kernels lower to Mosaic kernels; the posterior-grid
-kernel lays every per-worker array out as (K, 1, X) with the worker axis
-squeezed from its blocks (see ``posterior_grid``).  On any other backend
-they run with ``interpret=True``, which emulates the kernel body and exists
-for the CPU tests only.  The switch follows ``jax.default_backend``.
+kernel takes the (K, N) telemetry as it is and picks its tile from the
+shapes (see ``posterior_grid``).  On any other backend they run with
+``interpret=True``, which emulates the kernel body and exists for the CPU
+tests only.  The switch follows ``jax.default_backend``.
 """
 from __future__ import annotations
 

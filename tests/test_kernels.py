@@ -1,4 +1,6 @@
 """Pallas kernels vs pure-jnp oracles (interpret=True): shape/dtype sweeps."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -42,7 +44,13 @@ def _assert_logp_close(got, want, rtol=2e-5):
 
 
 @pytest.mark.parametrize("zero_cols", [False, True])
-@pytest.mark.parametrize("k,g,n", [(1, 64, 100), (3, 300, 777), (4, 512, 128), (5, 17, 33)])
+@pytest.mark.parametrize(
+    "k,g,n",
+    [(1, 64, 100), (3, 300, 777), (4, 512, 128), (5, 17, 33),
+     # K off the 8-worker groups; N = 64 (the ring) unpadded; N past the N
+     # block; G off the 128 lanes
+     (13, 256, 64), (17, 300, 64), (2, 64, 777), (2, 17, 4096)],
+)
 def test_posterior_grid_fleet_parity(k, g, n, zero_cols):
     """One fused launch (interpret mode) == unified oracle, both modes, for
     odd/padded G and N, per-worker priors, and zero-mask columns."""
@@ -50,7 +58,7 @@ def test_posterior_grid_fleet_parity(k, g, n, zero_cols):
     grid = jnp.linspace(1e-4, 1 - 1e-4, g, dtype=jnp.float32)
     got = posterior_grid_fleet_pallas(
         grid, t, f, mask, mu, lam, alpha, beta, ap.a, ap.b, bp.a, bp.b,
-        interpret=True, block_g=64, block_n=256,
+        interpret=True, block_g=128, block_n=256,
     )
     want = log_posterior_grid(grid, t, f, mu, lam, alpha, beta, ap, bp, mask)
     assert got.shape == (k, 2, g)
@@ -137,6 +145,50 @@ def test_posterior_grid_fleet_fully_masked_worker():
     )
 
 
+@pytest.mark.parametrize("k,pad", [(13, 3), (17, 9)])
+def test_posterior_grid_fleet_masked_pad_rows(k, pad):
+    """Fully masked trailing rows, as the sharded launch pads a fleet, at
+    the served width (G = 256, N = 64) and the default tile: the real rows
+    match the oracle and the pad rows read their prior."""
+    n, g = 64, 256
+    t, f, mask, mu, lam, alpha, beta, ap, bp = _fleet_case(k + pad, n, seed=11)
+    mask = mask.at[k:].set(0.0)
+    grid = jnp.linspace(1e-4, 1 - 1e-4, g, dtype=jnp.float32)
+    got = posterior_grid_fleet_pallas(
+        grid, t, f, mask, mu, lam, alpha, beta, ap.a, ap.b, bp.a, bp.b,
+        interpret=True,
+    )
+    want = log_posterior_grid(grid, t, f, mu, lam, alpha, beta, ap, bp, mask)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    _assert_logp_close(got, want)
+    gc = jnp.clip(grid, 1e-6, 1 - 1e-6)
+    prior = lambda p: (p.a[k:, None] - 1.0) * jnp.log(gc) + (
+        p.b[k:, None] - 1.0) * jnp.log1p(-gc)
+    np.testing.assert_allclose(np.asarray(got[k:, 0]), np.asarray(prior(ap)),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(got[k:, 1]), np.asarray(prior(bp)),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_posterior_grid_fleet_counts_its_padding():
+    """Tracing the launcher adds the useful cells K*G*N and the cells its
+    tile evaluates to the obs counters; at the ring's N = 64 the tile pads
+    next to nothing."""
+    from repro import obs
+
+    k, g, n = 16, 256, 64
+    args = [jax.ShapeDtypeStruct(s, jnp.float32)
+            for s in [(g,), (k, n), (k, n), (k, n)] + [(k,)] * 8]
+    names = ("kernels.posterior_grid.cells", "kernels.posterior_grid.padded_cells")
+    before = [obs.snapshot()["counters"].get(name, 0) for name in names]
+    launcher = posterior_grid_fleet_pallas.__wrapped__  # trace afresh
+    jax.eval_shape(functools.partial(launcher, interpret=True), *args)
+    cells, padded = (obs.snapshot()["counters"].get(name, 0) - b
+                     for name, b in zip(names, before))
+    assert cells == k * g * n
+    assert cells <= padded <= 1.1 * cells
+
+
 @pytest.mark.parametrize("mode", ["alpha", "beta"])
 @pytest.mark.parametrize("g,n", [(64, 100), (300, 777), (512, 2048), (17, 33)])
 def test_posterior_grid_shapes(mode, g, n):
@@ -150,7 +202,7 @@ def test_posterior_grid_shapes(mode, g, n):
             jnp.float32(2.0), jnp.float32(3.0))
     got = posterior_grid_pallas(
         grid, t, f, mask, *args, mode=mode, interpret=True,
-        block_g=64, block_n=256,
+        block_g=128, block_n=256,
     )
     want = ref.posterior_grid_ref(
         grid, t, f, args[0], args[1], args[2], args[3], args[4], mask, mode=mode
@@ -160,12 +212,13 @@ def test_posterior_grid_shapes(mode, g, n):
                                rtol=2e-5, atol=2e-5 * scale)
 
 
-@pytest.mark.parametrize("block_g,block_n", [(8, 128), (128, 512), (256, 1024)])
+@pytest.mark.parametrize("block_g,block_n", [(128, 128), (128, 512), (256, 1024)])
 def test_posterior_grid_block_invariance(block_g, block_n):
-    """Result must not depend on the tiling."""
+    """Result must not depend on the tiling: 2-5 G blocks (G = 600 past the
+    default block of 512), 1-5 N blocks, partial last blocks of both."""
     key = jax.random.PRNGKey(5)
     kf, kt = jax.random.split(key)
-    n, g = 513, 100
+    n, g = 513, 600
     f = jax.random.uniform(kf, (n,), minval=0.1, maxval=0.9)
     t = f * 10.0 + jax.random.normal(kt, (n,))
     grid = jnp.linspace(1e-4, 1 - 1e-4, g, dtype=jnp.float32)
@@ -179,6 +232,19 @@ def test_posterior_grid_block_invariance(block_g, block_n):
         jnp.float32(2.0), jnp.float32(2.0), mask, mode="alpha",
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-4, atol=1e-3)
+
+
+@pytest.mark.parametrize("block_g,block_n", [(8, 128), (64, 256), (128, 200)])
+def test_posterior_grid_fleet_rejects_unaligned_blocks(block_g, block_n):
+    """A G or N block off the 128 lanes is refused, not silently rounded."""
+    k, g, n = 2, 100, 300
+    t, f, mask, mu, lam, alpha, beta, ap, bp = _fleet_case(k, n)
+    grid = jnp.linspace(1e-4, 1 - 1e-4, g, dtype=jnp.float32)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        posterior_grid_fleet_pallas(
+            grid, t, f, mask, mu, lam, alpha, beta, ap.a, ap.b, bp.a, bp.b,
+            interpret=True, block_g=block_g, block_n=block_n,
+        )
 
 
 def test_posterior_grid_ref_deprecation_names_unified_oracle():

@@ -71,13 +71,19 @@ def _shapes(sharding, *shapes):
         (10_000, 256, 64),  # the served shape: default ServeConfig, K = 10^4
         (3, 64, 4),  # the --serve-smoke grid (launch/serve.py)
         (16, 512, 4096),  # long telemetry
+        (1_152, 256, 64),  # the montage benchmark cell, folded: 9 x 128 slots
+        (12_583, 256, 64),  # the borg-cell benchmark cell
     ],
 )
 def test_fleet_kernel_compiles_for_v5e(one_chip, k, g, n):
     fn = functools.partial(posterior_grid_fleet_pallas, interpret=False)
     args = _shapes(one_chip, (g,), (k, n), (k, n), (k, n), *[(k,)] * 8)
     compiled = jax.jit(fn).lower(*args).compile()
-    assert KERNEL_OP in compiled.as_text()
+    # The benchmark's kernel readers find the kernel by this instruction name.
+    assert any(
+        line.lstrip().startswith("%posterior_grid_fleet_pallas") and KERNEL_OP in line
+        for line in compiled.as_text().splitlines()
+    )
 
 
 def _wrapper_args(sharding, lead, g, n):
