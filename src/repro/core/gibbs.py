@@ -234,21 +234,24 @@ def _advance_active(
     """
     m = jnp.ones_like(t) if mask is None else jnp.broadcast_to(mask, t.shape)
 
-    take = lambda x: x[active_idx]
-    slab = jax.tree_util.tree_map(take, state)
-    slab, ll_slab = _advance(
-        slab, take(t), take(f), take(m),
-        n_iters=n_iters, grid_size=grid_size, use_pallas=use_pallas,
-        chain_priors=chain_priors,
-    )
+    with jax.named_scope("active_slab"):
+        take = lambda x: x[active_idx]
+        slab = jax.tree_util.tree_map(take, state)
+        slab, ll_slab = _advance(
+            slab, take(t), take(f), take(m),
+            n_iters=n_iters, grid_size=grid_size, use_pallas=use_pallas,
+            chain_priors=chain_priors,
+        )
 
-    rest, ll_rest = _advance_surrogate(
-        state, t, f, m, n_iters=n_iters, chain_priors=chain_priors
-    )
+    with jax.named_scope("surrogate_sweep"):
+        rest, ll_rest = _advance_surrogate(
+            state, t, f, m, n_iters=n_iters, chain_priors=chain_priors
+        )
 
-    put = lambda full, part: full.at[active_idx].set(part)
-    merged = jax.tree_util.tree_map(put, rest, slab)
-    return merged, put(ll_rest, ll_slab)
+    with jax.named_scope("active_merge"):
+        put = lambda full, part: full.at[active_idx].set(part)
+        merged = jax.tree_util.tree_map(put, rest, slab)
+        return merged, put(ll_rest, ll_slab)
 
 
 @functools.partial(

@@ -138,6 +138,10 @@ class TickInfo(NamedTuple):
     drained: Array  # int32 observations consumed from the ring
     fired: Array  # bool: the drift gate fired (else a propose is the
     # staleness cap's)
+    refreshed: Array  # int32 workers given a full grid evaluation: K on a
+    # dense drain, M on an active-set drain, 0 on an empty tick
+    oldest: Array  # int32 largest ``refresh_age`` among live workers after
+    # the tick (0 on the dense path, where every drain refreshes everyone)
 
 
 def posterior_drift(ref: UnitParams, cur: UnitParams) -> Array:
@@ -251,25 +255,36 @@ def _tick_body(
     k = state.fractions.shape[0]
     active_idx = None
     refresh_age = state.refresh_age
+    refreshed = jnp.where(has_data, k, 0).astype(jnp.int32)
     if config.active_size is not None and config.active_size < k:
         m = config.active_size
-        active_idx, _ = select_active(
-            m,
-            age=state.refresh_age,
-            nu=state.sched.gibbs.ng.nu0,
-            surprise=(
-                _surprise_body(state.sched.gibbs, state.hyper)
-                if config.sched.hierarchical
-                else None
-            ),
-            anomaly=state.sched.ewma_ll,
-            live=state.sched.live,
+        with jax.named_scope("active_select"):
+            active_idx, _ = select_active(
+                m,
+                age=state.refresh_age,
+                nu=state.sched.gibbs.ng.nu0,
+                surprise=(
+                    _surprise_body(state.sched.gibbs, state.hyper)
+                    if config.sched.hierarchical
+                    else None
+                ),
+                anomaly=state.sched.ewma_ll,
+                live=state.sched.live,
+            )
+            refresh_age = jnp.where(
+                has_data,
+                (state.refresh_age + 1).at[active_idx].set(0),
+                state.refresh_age,
+            )
+        refreshed = jnp.where(has_data, m, 0).astype(jnp.int32)
+        live_age = (
+            refresh_age
+            if state.sched.live is None
+            else jnp.where(state.sched.live > 0, refresh_age, 0)
         )
-        refresh_age = jnp.where(
-            has_data,
-            (state.refresh_age + 1).at[active_idx].set(0),
-            state.refresh_age,
-        )
+        oldest = jnp.max(live_age)
+    else:  # dense: every drain refreshes every worker
+        oldest = jnp.zeros((), jnp.int32)
 
     def advance(sched_state):
         fleet, _ = advance_fleet(
@@ -387,7 +402,8 @@ def _tick_body(
         refresh_age=refresh_age,
     )
     return new_state, TickInfo(
-        proposed=should, drift=drift, drained=drained, fired=fired
+        proposed=should, drift=drift, drained=drained, fired=fired,
+        refreshed=refreshed, oldest=oldest,
     ), cur
 
 
@@ -465,7 +481,8 @@ class ServiceLoop:
         self._pending: Optional[Tuple[Array, ProposeStats]] = None
         self._pending_beat = 0  # the beat whose tick dispatched _pending
         self._beat = 0  # ticks so far
-        self._host = dict(rows_drained=0, proposes_gate=0, proposes_stale=0)
+        self._host = dict(rows_drained=0, proposes_gate=0, proposes_stale=0,
+                          grid_refreshed=0)
 
     # -- ingestion (producer side) -----------------------------------------
     def push(self, fracs, times, valid=None) -> None:
@@ -490,7 +507,7 @@ class ServiceLoop:
         as the synchronous path does.  A solve already in flight suppresses
         re-dispatch; the gate refires on a later beat if drift persists.
 
-        The tick waits on the device once, for its three flags.
+        The tick waits on the device once, for its flags and tallies.
         """
         beat = self._beat
         with obs.span("serve.tick", beat=beat) as sp:
@@ -502,12 +519,15 @@ class ServiceLoop:
             else:
                 self.state, info = tick(self.state, self.config)
             with obs.span("serve.wait"):  # the fleet stays on the device
-                proposed, fired, drained = jax.device_get(
-                    (info.proposed, info.fired, info.drained)
+                flags = jax.device_get(
+                    (info.proposed, info.fired, info.drained,
+                     info.refreshed, info.oldest)
                 )
-            proposed, fired, drained = bool(proposed), bool(fired), int(drained)
-            sp.set(proposed=proposed, fired=fired, drained=drained)
-            self._tally(proposed, fired, drained)
+            proposed, fired = bool(flags[0]), bool(flags[1])
+            drained, refreshed, oldest = (int(x) for x in flags[2:])
+            sp.set(proposed=proposed, fired=fired, drained=drained,
+                   refreshed=refreshed, oldest=oldest)
+            self._tally(proposed, fired, drained, refreshed)
             if proposed and not self.config.async_propose:
                 self._publish(self.state.fractions, beat)
             elif proposed and self._pending is None:
@@ -519,9 +539,12 @@ class ServiceLoop:
         self._beat = beat + 1
         return info
 
-    def _tally(self, proposed: bool, fired: bool, drained: int) -> None:
+    def _tally(
+        self, proposed: bool, fired: bool, drained: int, refreshed: int
+    ) -> None:
         """Add one tick to this loop's host tallies (``counters()``)."""
         self._host["rows_drained"] += drained
+        self._host["grid_refreshed"] += refreshed
         if proposed:
             self._host["proposes_gate" if fired else "proposes_stale"] += 1
 
@@ -563,9 +586,10 @@ class ServiceLoop:
     # -- observability ------------------------------------------------------
     def counters(self) -> dict:
         """Lifetime drain/propose/drop counters, read in one transfer, and
-        this loop's host tallies: ``rows_drained`` and the proposes of the
+        this loop's host tallies: ``rows_drained``, the proposes of the
         gate and of the staleness cap (they sum to ``proposes`` for a loop
-        started from a fresh state)."""
+        started from a fresh state), and ``grid_refreshed``, the workers
+        given a full grid evaluation summed over ticks."""
         s = self.state
         drains, proposes, dropped, pushes = jax.device_get(
             (s.n_drains, s.n_proposes, s.ring.dropped, s.ring.total)

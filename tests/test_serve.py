@@ -247,6 +247,8 @@ def test_tick_records_carry_flags_and_share_a_beat_with_their_pushes():
             "proposed": bool(info.proposed),
             "fired": bool(info.fired),
             "drained": int(info.drained),
+            "refreshed": int(info.refreshed),
+            "oldest": int(info.oldest),
         }
         mine = [s for s in spans if s["beat"] == t["beat"]]
         pushes = [s for s in mine if s["name"] == "serve.push"]

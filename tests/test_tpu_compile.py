@@ -137,6 +137,29 @@ def test_service_tick_compiles_for_v5e(one_chip, mosaic):
     assert KERNEL_OP in compiled.as_text()
 
 
+def test_active_service_tick_compiles_for_v5e(one_chip, mosaic):
+    """The active-set tick of the borg-fleet-active benchmark cell: K = 10^5,
+    a top-6,250 selection, the kernel on the gathered slab, the surrogate
+    sweeps over the fleet, and the scopes that name those stages."""
+    from repro import sched, serve
+    from repro.serve import service
+
+    config = serve.ServeConfig(
+        sched=sched.SchedulerConfig(grid_size=256), active_size=6_250
+    )
+    state = jax.eval_shape(
+        lambda key: service.init(config, 100_000, key), jax.random.PRNGKey(0)
+    )
+    state = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        state,
+    )
+    text = service.tick.lower(state, config).compile().as_text()
+    assert KERNEL_OP in text
+    for scope in ("active_select", "active_slab", "surrogate_sweep", "active_merge"):
+        assert scope in text, scope
+
+
 def test_chip_smoke_stops_at_the_device_check(monkeypatch, capsys):
     """Without a TPU the smoke run exits non-zero before any phase and
     prints no result line."""
