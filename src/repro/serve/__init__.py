@@ -32,7 +32,7 @@ True
 True
 """
 from .gate import GateState, gate_init, gate_threshold, gate_update
-from .ring import DrainedBatch, TelemetryRing, drain, push, ring_init
+from .ring import DrainedBatch, TelemetryRing, drain, push, push_rows, ring_init
 from .service import (
     ServeConfig,
     ServeState,
@@ -60,6 +60,7 @@ __all__ = [
     "init",
     "posterior_drift",
     "push",
+    "push_rows",
     "ring_init",
     "solve_published",
     "tick",

@@ -5,8 +5,11 @@ consumes it in batches.  ``TelemetryRing`` decouples the two rates without
 ever leaving the device or changing a shape:
 
   * every leaf is a fixed-capacity array — ``push`` writes one slot with a
-    dynamic-index ``.at[slot].set`` and ``drain`` reads the whole buffer with
-    a masked tail, so both compile once and never host-sync;
+    dynamic-index ``.at[slot].set``, ``push_rows`` writes a block of rows
+    with one scatter (the path of ``ServiceLoop``, which stages its rows on
+    the host and writes them once a tick), and ``drain`` reads the whole
+    buffer with a masked tail, so none of them changes a shape or
+    host-syncs;
   * the buffer is a plain pytree (NamedTuple of arrays): it rides through
     ``jax.jit`` (with buffer donation for zero-copy advance), checkpoints
     through ``CheckpointManager``, and vmaps for multi-tenant deployments;
@@ -130,6 +133,51 @@ def push(
         count=jnp.minimum(ring.count + 1, cap),
         dropped=ring.dropped + full,
         total=ring.total + 1,
+    )
+
+
+def push_rows(
+    ring: TelemetryRing,
+    fracs: Array,
+    times: Array,
+    n: Array,
+    valid: Optional[Array] = None,
+) -> TelemetryRing:
+    """Append the first ``n`` rows of an ``(R, ...)`` block; jit-compatible.
+
+    The result is exactly that of ``n`` calls of :func:`push` on rows
+    ``0 .. n-1`` in order: row ``i`` lands in slot ``(head + i) % capacity``,
+    ``count`` saturates at capacity, every row that finds the ring full
+    counts in ``dropped``, and invalid elements are stored as the dummies
+    ``push`` stores.  Rows ``i >= n`` are padding and never written, so one
+    compiled program serves every ``n <= R``; ``R`` may not exceed the
+    capacity.  ``n`` is traced.
+    """
+    cap = ring.capacity
+    r = times.shape[0]
+    if r > cap:
+        raise ValueError(f"a block of {r} rows exceeds the capacity {cap}")
+    f = jnp.asarray(fracs, ring.fracs.dtype)
+    t = jnp.asarray(times, ring.times.dtype)
+    if valid is None:
+        v = jnp.ones(t.shape, ring.valid.dtype)
+    else:
+        v = jnp.broadcast_to(jnp.asarray(valid, ring.valid.dtype), t.shape)
+    f = jnp.where(v > 0, f, 0.5)
+    t = jnp.where(v > 0, t, 1.0)
+    n = jnp.asarray(n, jnp.int32)
+    i = jnp.arange(r, dtype=jnp.int32)
+    # Padding rows aim at slot ``cap``, out of bounds, which ``drop`` skips.
+    slots = jnp.where(i < n, (ring.head + i) % cap, cap)
+    put = lambda buf, rows: buf.at[slots].set(rows, mode="drop")
+    return TelemetryRing(
+        fracs=put(ring.fracs, f),
+        times=put(ring.times, t),
+        valid=put(ring.valid, v),
+        head=(ring.head + n) % cap,
+        count=jnp.minimum(ring.count + n, cap),
+        dropped=ring.dropped + jnp.maximum(ring.count + n - cap, 0),
+        total=ring.total + n,
     )
 
 

@@ -65,7 +65,7 @@ from .gate import (
     gate_init,
     gate_update,
 )
-from .ring import TelemetryRing, drain, push, ring_init
+from .ring import TelemetryRing, drain, push_rows, ring_init
 
 Array = jax.Array
 
@@ -437,23 +437,36 @@ def tick_with_params(
     return _tick_body(state, config)
 
 
+# Donated block write: the ring's slot buffers advance in place.  One trace
+# per block height, a power of two up to the capacity.
+_push_rows = jax.jit(push_rows, donate_argnums=(0,))
+
+
 class ServiceLoop:
     """Imperative shell of the push-mode service: jit closures built ONCE.
 
-    The loop owns a ``ServeState`` and three compiled entry points — a
-    donated ``push``, the donated fused ``tick``, and nothing else; no
-    request ever triggers a re-trace.  Published fractions live in a
-    double-buffered host slot: ``fractions()`` reads whichever buffer is
-    active without taking a lock or touching a device, so request threads
-    never wait on a Gibbs sweep (``docs/serving.md``).
+    The loop owns a ``ServeState`` and two compiled entry points — a
+    donated block write of the ring (``push_rows``) and the donated fused
+    ``tick``; no request ever triggers a re-trace beyond the block heights.
+    Published fractions live in a double-buffered host slot: ``fractions()``
+    reads whichever buffer is active without taking a lock or touching a
+    device, so request threads never wait on a Gibbs sweep
+    (``docs/serving.md``).
 
-    ``state`` is the checkpointable pytree — hand it to
-    ``CheckpointManager.save`` and assign it back after restore.
+    ``push`` stages rows in a host block of ``config.capacity`` rows.  The
+    block reaches the ring as one transfer and one write (a flush) at the
+    start of ``tick``, at a read of ``state`` or ``counters()``, and at once
+    when a ring's worth of rows is staged, so the host never holds more than
+    one ring of telemetry and overflow is counted on the device as before.
 
-    Every push, tick and publication is a ``repro.obs`` span (``serve.*``,
-    ``docs/serving.md`` "Observability") carrying ``beat``, the number of
-    ticks before it: the pushes a tick drains share its beat, and so does the
-    publication of the split it solves.
+    ``state`` is the checkpointable pytree, with every pushed row in its
+    ring — hand it to ``CheckpointManager.save`` and assign it back after
+    restore.
+
+    Every push, flush, tick and publication is a ``repro.obs`` span
+    (``serve.*``, ``docs/serving.md`` "Observability") carrying ``beat``, the
+    number of ticks before it: the pushes a tick drains share its beat, and
+    so do their flush and the publication of the split it solves.
     """
 
     def __init__(
@@ -465,16 +478,25 @@ class ServiceLoop:
         state: Optional[ServeState] = None,
     ):
         self.config = config or ServeConfig()
-        self.state = (
+        self._state = (
             state
             if state is not None
             else init(self.config, num_workers, jax.random.PRNGKey(seed))
         )
-        # Donated push: the ring's slot buffers advance in place.
-        self._push = jax.jit(push, donate_argnums=(0,))
+        # Two host blocks of (fracs, times, valid) rows, filled in turn; a
+        # block is refilled only once the flush that read it has completed.
+        shape = (self.config.capacity,) + self._state.ring.times.shape[1:]
+        self._blocks = [
+            tuple(np.empty(shape, np.float32) for _ in range(3))
+            for _ in range(2)
+        ]
+        self._block = 0  # the block being filled
+        self._staged = 0  # rows staged in it
+        self._staged_valid = False  # whether any of them carried ``valid``
+        self._in_flight = [False, False]  # a flush may still read the block
         self._slots = [
-            np.asarray(self.state.fractions).copy(),
-            np.asarray(self.state.fractions).copy(),
+            np.asarray(self._state.fractions).copy(),
+            np.asarray(self._state.fractions).copy(),
         ]
         self._active = 0
         self._version = 0
@@ -482,19 +504,67 @@ class ServiceLoop:
         self._pending_beat = 0  # the beat whose tick dispatched _pending
         self._beat = 0  # ticks so far
         self._host = dict(rows_drained=0, proposes_gate=0, proposes_stale=0,
-                          grid_refreshed=0)
+                          grid_refreshed=0, flushes=0)
+
+    @property
+    def state(self) -> ServeState:
+        """The service state, with every pushed row written into its ring."""
+        self._flush()
+        return self._state
+
+    @state.setter
+    def state(self, value: ServeState) -> None:
+        self._flush()  # staged rows belong to the state they were pushed to
+        if any(self._in_flight):
+            jax.block_until_ready(self._state.ring)
+            self._in_flight = [False, False]
+        self._state = value
 
     # -- ingestion (producer side) -----------------------------------------
     def push(self, fracs, times, valid=None) -> None:
-        """Buffer one telemetry row; returns immediately (device-async)."""
+        """Stage one telemetry row on the host (a float32 copy); returns at
+        once.  ``valid`` optionally marks elements invalid, as in
+        ``ring.push``."""
         with obs.span("serve.push", beat=self._beat):
-            ring = self._push(
-                self.state.ring,
-                jnp.asarray(fracs, jnp.float32),
-                jnp.asarray(times, jnp.float32),
-                None if valid is None else jnp.asarray(valid, jnp.float32),
-            )
-            self.state = self.state._replace(ring=ring)
+            i = self._staged
+            f, t, v = self._blocks[self._block]
+            f[i] = fracs
+            t[i] = times
+            if valid is not None:
+                if not self._staged_valid:
+                    v[:i] = 1.0
+                    self._staged_valid = True
+                v[i] = valid
+            elif self._staged_valid:
+                v[i] = 1.0
+            self._staged = i + 1
+            if self._staged == self.config.capacity:
+                self._flush()
+
+    def _flush(self) -> None:
+        """Write the staged rows into the ring: one transfer of the block,
+        padded to a power of two, and one donated ``push_rows``."""
+        n = self._staged
+        if n == 0:
+            return
+        with obs.span("serve.flush", beat=self._beat, rows=n):
+            other = 1 - self._block
+            if self._in_flight[other]:  # the ring is the output of its flush
+                jax.block_until_ready(self._state.ring)
+                self._in_flight[other] = False
+            height = min(1 << (n - 1).bit_length(), self.config.capacity)
+            f, t, v = self._blocks[self._block]
+            staged = jax.device_put((
+                f[:height], t[:height], np.int32(n),
+                v[:height] if self._staged_valid else None,
+            ))
+            ring = _push_rows(self._state.ring, *staged)
+            self._state = self._state._replace(ring=ring)
+        self._in_flight[self._block] = True
+        self._block = other
+        self._staged = 0
+        self._staged_valid = False
+        self._host["flushes"] += 1
 
     # -- the service beat (estimator side) ---------------------------------
     def tick(self) -> TickInfo:
@@ -507,33 +577,37 @@ class ServiceLoop:
         as the synchronous path does.  A solve already in flight suppresses
         re-dispatch; the gate refires on a later beat if drift persists.
 
-        The tick waits on the device once, for its flags and tallies.
+        The staged rows are flushed into the ring first.  The tick waits on
+        the device once, for its flags and tallies; by then every flush
+        before it has completed.
         """
         beat = self._beat
         with obs.span("serve.tick", beat=beat) as sp:
+            self._flush()
             if self.config.async_propose:
                 self.poll()
-                self.state, info, cur = tick_with_params(
-                    self.state, self.config
+                self._state, info, cur = tick_with_params(
+                    self._state, self.config
                 )
             else:
-                self.state, info = tick(self.state, self.config)
+                self._state, info = tick(self._state, self.config)
             with obs.span("serve.wait"):  # the fleet stays on the device
                 flags = jax.device_get(
                     (info.proposed, info.fired, info.drained,
                      info.refreshed, info.oldest)
                 )
+            self._in_flight = [False, False]
             proposed, fired = bool(flags[0]), bool(flags[1])
             drained, refreshed, oldest = (int(x) for x in flags[2:])
             sp.set(proposed=proposed, fired=fired, drained=drained,
                    refreshed=refreshed, oldest=oldest)
             self._tally(proposed, fired, drained, refreshed)
             if proposed and not self.config.async_propose:
-                self._publish(self.state.fractions, beat)
+                self._publish(self._state.fractions, beat)
             elif proposed and self._pending is None:
                 with obs.span("serve.dispatch_solve"):
                     self._pending = solve_published(
-                        cur, self.config, self.state.sched.live
+                        cur, self.config, self._state.sched.live
                     )
                 self._pending_beat = beat
         self._beat = beat + 1
@@ -562,7 +636,7 @@ class ServiceLoop:
             if not fr.is_ready():
                 return False
             self._pending = None
-            self.state = self.state._replace(fractions=fr, stats=st)
+            self._state = self._state._replace(fractions=fr, stats=st)
             self._publish(fr, self._pending_beat)
         return True
 
@@ -589,7 +663,9 @@ class ServiceLoop:
         this loop's host tallies: ``rows_drained``, the proposes of the
         gate and of the staleness cap (they sum to ``proposes`` for a loop
         started from a fresh state), and ``grid_refreshed``, the workers
-        given a full grid evaluation summed over ticks."""
+        given a full grid evaluation summed over ticks, and ``flushes``, the
+        block writes that carried pushed rows into the ring.  Staged rows
+        are flushed first, so ``pushes`` counts every row pushed."""
         s = self.state
         drains, proposes, dropped, pushes = jax.device_get(
             (s.n_drains, s.n_proposes, s.ring.dropped, s.ring.total)
@@ -604,4 +680,4 @@ class ServiceLoop:
 
     @property
     def num_workers(self) -> int:
-        return int(self.state.fractions.shape[0])
+        return int(self._state.fractions.shape[0])

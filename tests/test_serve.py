@@ -9,7 +9,9 @@ The load-bearing claims:
      regime), not on steady-state sampling noise;
   4. the donated tick/push path re-uses buffers: no per-step growth in
      live device arrays;
-  5. the service state checkpoints and restores through CheckpointManager.
+  5. the service state checkpoints and restores through CheckpointManager;
+  6. rows staged on the host and written as one block reach the ring
+     bitwise as the same rows pushed one by one.
 """
 import gc
 import subprocess
@@ -133,6 +135,51 @@ def test_fleet_ring_layout_and_validity_mask():
     np.testing.assert_array_equal(np.asarray(batch.mask), [[1, 1, 0], [0, 1, 0]])
     # the invalid inf never got stored (0 * inf = nan would leak)
     assert np.isfinite(np.asarray(batch.times)).all()
+
+
+@pytest.mark.parametrize(
+    "cap,before,buffered,n,height,with_valid",
+    [
+        (8, 0, 0, 1, 1, False),  # one row into an empty ring
+        (8, 3, 4, 5, 8, True),  # head 7, 4 buffered: wraps, drops one
+        (64, 40, 30, 64, 64, False),  # head 6, 30 buffered: drops 30
+    ],
+)
+def test_push_rows_is_bitwise_sequential_pushes(
+    cap, before, buffered, n, height, with_valid
+):
+    """A block write of n rows == n single-row pushes: slots, wrap,
+    saturated count, dropped, total and the dummies of invalid elements;
+    the block's padding rows are never written."""
+    k = 3
+    rng = np.random.default_rng(cap + n)
+    rows = lambda m: (rng.uniform(0.1, 0.9, (m, k)).astype(np.float32),
+                      rng.uniform(1.0, 9.0, (m, k)).astype(np.float32))
+    ring = serve.ring_init(cap, num_workers=k)
+    for f, t in zip(*rows(before)):
+        ring = serve.push(ring, f, t)
+    _, ring = serve.drain(ring)
+    for f, t in zip(*rows(buffered)):
+        ring = serve.push(ring, f, t)
+    assert int(ring.head) == (before + buffered) % cap
+    assert int(ring.count) == buffered
+
+    f, t = rows(height)
+    f[n:], t[n:] = np.nan, np.inf  # padding: must never reach the ring
+    valid = None
+    if with_valid:
+        valid = (rng.uniform(size=(height, k)) > 0.3).astype(np.float32)
+        t[0, 0], f[1, 2] = np.inf, np.nan
+        valid[0, 0] = valid[1, 2] = 0.0
+    want = ring
+    for i in range(n):
+        want = serve.push(want, f[i], t[i], None if valid is None else valid[i])
+    got = jax.jit(serve.push_rows)(ring, f, t, n, valid)
+    assert _leaves_equal(got, want)
+    assert int(got.dropped) == max(0, buffered + n - cap)
+    assert int(got.total) == before + buffered + n
+    assert int(got.count) == min(cap, buffered + n)
+    assert np.isfinite(np.asarray(got.times)).all()
 
 
 # ------------------------------------------------------------------- cadence
@@ -270,8 +317,104 @@ def test_ten_ticks_trace_the_tick_once():
     _push_rounds(loop, mu, 10, rng)
     after = obs.snapshot()["traces"]
     assert after.get("tick", 0) - before.get("tick", 0) == 1
-    assert after.get("push", 0) - before.get("push", 0) <= 1
+    assert after.get("push_rows", 0) - before.get("push_rows", 0) <= 1
     assert loop.counters()["drains"] == 10
+
+
+# ------------------------------------------------------------ host staging
+def _rows(n, k, seed):
+    rng = np.random.default_rng(seed)
+    f = rng.uniform(0.1, 0.9, (n, k)).astype(np.float32)
+    t = (f**0.9 * 4.0 + 0.1 * rng.standard_normal((n, k))).astype(np.float32)
+    return f, t
+
+
+def _ref_push(loop, f, t, valid=None):
+    """The reference: one row written straight into the loop's ring."""
+    s = loop.state
+    loop.state = s._replace(ring=serve.push(s.ring, f, t, valid))
+
+
+def test_staged_pushes_overflow_bitwise_like_sequential_pushes():
+    """C + 3 staged rows (some carrying ``valid``) and a tick: the same
+    state and counters as the rows pushed one by one, three dropped."""
+    cfg = _steady_cfg()
+    cap, k = cfg.capacity, 3
+    f, t = _rows(cap + 3, k, seed=8)
+    valid = np.ones((cap + 3, k), np.float32)
+    t[2, 1], valid[2, 1] = np.inf, 0.0
+    loop = serve.ServiceLoop(k, config=cfg, seed=5)
+    ref = serve.ServiceLoop(k, config=cfg, seed=5)
+    for i in range(cap + 3):
+        v = valid[i] if i in (2, cap + 1) else None
+        loop.push(f[i], t[i], v)
+        _ref_push(ref, f[i], t[i], v)
+    i1, i2 = loop.tick(), ref.tick()
+    assert _leaves_equal(i1, i2)
+    assert _leaves_equal(loop.state, ref.state)
+    c, want = loop.counters(), ref.counters()
+    assert c.pop("flushes") == 2 and want.pop("flushes") == 0
+    assert c == want
+    assert c["dropped"] == 3 and c["pushes"] == cap + 3
+
+
+def test_state_and_counters_read_mid_beat_see_staged_rows():
+    cfg = _steady_cfg()
+    k = 2
+    f, t = _rows(7, k, seed=9)
+    loop = serve.ServiceLoop(k, config=cfg, seed=6)
+    ref = serve.ServiceLoop(k, config=cfg, seed=6)
+    push = lambda rows: [(loop.push(f[i], t[i]), _ref_push(ref, f[i], t[i]))
+                         for i in rows]
+    push(range(3))
+    assert loop.counters()["pushes"] == 3
+    push(range(3, 5))
+    assert int(loop.state.ring.count) == 5
+    assert _leaves_equal(loop.state.ring, ref.state.ring)
+    push(range(5, 7))
+    assert int(loop.tick().drained) == int(ref.tick().drained) == 7
+    assert _leaves_equal(loop.state, ref.state)
+    assert loop.counters()["flushes"] == 3  # counters(), state, tick
+
+
+def test_flush_program_traces_once_per_block_height():
+    """Rows are shipped in blocks padded to a power of two: one trace per
+    height, and the tick still traces once."""
+    # a worker count no other test uses, so the first flushes here trace
+    cfg = _steady_cfg(capacity=16)
+    k = 7
+    loop = serve.ServiceLoop(k, config=cfg, seed=0)
+    ref = serve.ServiceLoop(k, config=cfg, seed=0)
+    before = obs.snapshot()["traces"]
+    seed = 0
+    for n in (1, 2, 3, 4, 5, 8, 9, 16, 3, 16, 7):
+        seed += 1
+        f, t = _rows(n, k, seed)
+        for i in range(n):
+            loop.push(f[i], t[i])
+            _ref_push(ref, f[i], t[i])
+        assert int(loop.tick().drained) == int(ref.tick().drained) == n
+    after = obs.snapshot()["traces"]
+    assert after.get("push_rows", 0) - before.get("push_rows", 0) == 5
+    assert after.get("tick", 0) - before.get("tick", 0) == 1
+    assert _leaves_equal(loop.state, ref.state)
+
+
+def test_flushes_count_one_per_data_carrying_tick():
+    cfg = _steady_cfg()
+    k = 2
+    loop = serve.ServiceLoop(k, config=cfg, seed=1)
+    obs.reset()
+    for beat, n in enumerate((3, 0, cfg.capacity, 5)):
+        f, t = _rows(n, k, seed=beat)
+        for i in range(n):
+            loop.push(f[i], t[i])
+        loop.tick()
+    c = loop.counters()
+    assert c["flushes"] == c["drains"] == 3
+    flushes = [s for s in obs.snapshot()["spans"] if s["name"] == "serve.flush"]
+    assert [(s["beat"], s["attrs"]["rows"]) for s in flushes] == [
+        (0, 3), (2, cfg.capacity), (3, 5)]
 
 
 # ------------------------------------------------------------ donation/memory

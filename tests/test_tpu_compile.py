@@ -160,6 +160,26 @@ def test_active_service_tick_compiles_for_v5e(one_chip, mosaic):
         assert scope in text, scope
 
 
+@pytest.mark.parametrize("with_valid", [False, True])
+def test_flush_program_compiles_for_v5e(one_chip, with_valid):
+    """The service shell's block write at the borg-fleet-active cell's
+    shape: 64 staged rows of K = 10^5 into a 64-slot ring, the ring's
+    buffers updated in place."""
+    from repro.serve import ring_init, service
+
+    k, cap = 100_000, 64
+    ring = jax.eval_shape(lambda: ring_init(cap, k))
+    ring = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        ring,
+    )
+    rows = _shapes(one_chip, *[(cap, k)] * (3 if with_valid else 2))
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    valid = rows[2] if with_valid else None
+    compiled = service._push_rows.lower(ring, rows[0], rows[1], n, valid).compile()
+    assert "input_output_alias" in compiled.as_text()
+
+
 def test_chip_smoke_stops_at_the_device_check(monkeypatch, capsys):
     """Without a TPU the smoke run exits non-zero before any phase and
     prints no result line."""
