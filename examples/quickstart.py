@@ -7,8 +7,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import fit, optimal_two_way_fraction, sweep_two_way, pareto_mask
+from repro.core import fit, sweep_two_way, pareto_mask
 from repro.core.frontier import UnitParams
+from repro.sched import Objective, optimal_two_way_fraction
 
 # ---------------------------------------------------------------------------
 # 1. Two heterogeneous processing units (ground truth UNKNOWN to the system).
@@ -63,8 +64,8 @@ for k in range(0, 101, 10):
     star = "*" if mask[k] else ""
     print(f"  {float(fg[k]):.2f}   {float(mu_f[k]):6.2f} {float(var_f[k]):7.2f}  {star}")
 
-for obj, kw in [("mean", {}), ("mean_var", dict(risk_aversion=1.0)),
-                ("constrained", dict(var_budget=6.0))]:
-    f_opt, m, v = optimal_two_way_fraction(params, objective=obj, **kw)
-    print(f"objective={obj:11s} -> f*={float(f_opt):.3f} "
+for obj in [Objective.mean(), Objective.mean_var(1.0),
+            Objective.variance_budget(6.0)]:
+    f_opt, m, v = optimal_two_way_fraction(params, objective=obj)
+    print(f"objective={obj.kind:11s} -> f*={float(f_opt):.3f} "
           f"E[t]={float(m):.2f} Var[t]={float(v):.2f}")

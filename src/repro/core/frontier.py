@@ -13,7 +13,6 @@ point for a QoS target.
 """
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -376,65 +375,3 @@ def pareto_mask(mu_f: Array, var_f: Array) -> Array:
     )
     return ~dominated
 
-
-def optimal_two_way_fraction(
-    params: UnitParams,
-    *,
-    num_f: int = 201,
-    num_points: int = DEFAULT_QUAD_POINTS,
-    objective="mean",
-    risk_aversion: float = 0.0,
-    var_budget: float = float("inf"),
-) -> Tuple[Array, Array, Array]:
-    """Pick f on the frontier for K=2.
-
-    ``objective`` is a ``repro.sched.Objective`` — the same pluggable value
-    used by ``sched.propose`` and quantization — or one of the legacy strings
-    ("mean" | "mean_var" | "constrained") combined with the ``risk_aversion``
-    / ``var_budget`` floats.  Only the objective *kind* is jit-static: the
-    parameter floats stay traced, so sweeping risk_aversion or var_budget
-    reuses one compilation.  Returns (f*, mu(f*), sigma^2(f*)).
-    """
-    from repro.sched.objectives import Objective
-
-    if isinstance(objective, Objective):
-        risk_aversion = objective.risk_aversion
-        var_budget = objective.var_budget
-        deadline = objective.deadline
-        kind = objective.kind
-    else:
-        kind = {"constrained": "var_budget"}.get(objective, objective)
-        deadline = 0.0
-    return _optimal_two_way(
-        params,
-        jnp.asarray(risk_aversion, jnp.float32),
-        jnp.asarray(var_budget, jnp.float32),
-        jnp.asarray(deadline, jnp.float32),
-        kind=kind,
-        num_f=num_f,
-        num_points=num_points,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("kind", "num_f", "num_points"))
-def _optimal_two_way(
-    params: UnitParams,
-    risk_aversion: Array,
-    var_budget: Array,
-    deadline: Array,
-    *,
-    kind: str,
-    num_f: int,
-    num_points: int,
-) -> Tuple[Array, Array, Array]:
-    from repro.sched.objectives import score_moments_dynamic
-
-    f_grid, mu_f, var_f = sweep_two_way(params, num_f, num_points)
-    if kind == "deadline":
-        score = jax.vmap(
-            lambda f: -completion_cdf(deadline, jnp.stack([f, 1.0 - f]), params)
-        )(f_grid)
-    else:
-        score = score_moments_dynamic(kind, mu_f, var_f, risk_aversion, var_budget)
-    idx = jnp.argmin(score)
-    return f_grid[idx], mu_f[idx], var_f[idx]

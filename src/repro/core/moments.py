@@ -9,9 +9,9 @@ Beta distribution by the method of moments (Eqs 12-15).
 a fused pure-jnp oracle that emits BOTH exponent posteriors from one shared
 pow table, batched over an optional leading fleet axis.  It is exactly the
 formulation the Pallas TPU kernel (``repro.kernels.posterior_grid``)
-implements, so kernel/oracle parity is tight.  The historical single-mode
-entry points (``log_posterior_alpha_ref`` / ``log_posterior_beta_ref`` here,
-``repro.kernels.ref.posterior_grid_ref``) are thin slices of it.
+implements, so kernel/oracle parity is tight.  The single-mode entry points
+``log_posterior_alpha_ref`` (Eq 10) and ``log_posterior_beta_ref`` (Eq 11)
+are thin slices of it.
 """
 from __future__ import annotations
 

@@ -16,7 +16,7 @@ pure-jnp oracles the kernels are validated against.
 from . import ops, ref
 from .decode_attention import decode_attention_pallas
 from .lru_scan import lru_scan_pallas
-from .posterior_grid import posterior_grid_fleet_pallas, posterior_grid_pallas
+from .posterior_grid import posterior_grid_fleet_pallas
 
 __all__ = [
     "ops",
@@ -24,5 +24,4 @@ __all__ = [
     "decode_attention_pallas",
     "lru_scan_pallas",
     "posterior_grid_fleet_pallas",
-    "posterior_grid_pallas",
 ]

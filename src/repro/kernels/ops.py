@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from .decode_attention import decode_attention_pallas
 from .lru_scan import lru_scan_pallas
-from .posterior_grid import posterior_grid_fleet_pallas, posterior_grid_pallas
+from .posterior_grid import posterior_grid_fleet_pallas
 
 Array = jax.Array
 
@@ -143,50 +143,6 @@ def posterior_grid_fleet(
     # yield a prior-only posterior row that is sliced off and never
     # consulted.
     return shard_fleet_call(launch, sharding, args, mask_index=2)
-
-
-def posterior_grid_alpha(
-    grid: Array,
-    t: Array,
-    f: Array,
-    mu: Array,
-    lam: Array,
-    beta: Array,
-    prior,
-    mask: Optional[Array] = None,
-) -> Array:
-    """Eq 10 on a grid via the Pallas kernel.  Signature mirrors
-    ``repro.core.moments.log_posterior_alpha_ref``.
-
-    Back-compat single-mode entry: it slices one row out of the fused K=1
-    kernel, which still computes both exponents — production code wanting
-    both should call ``posterior_grid_fleet`` once."""
-    if mask is None:
-        mask = jnp.ones_like(t)
-    return posterior_grid_pallas(
-        grid, t, f, mask, mu, lam, beta, prior.a, prior.b,
-        mode="alpha", interpret=_interpret(),
-    )
-
-
-def posterior_grid_beta(
-    grid: Array,
-    t: Array,
-    f: Array,
-    mu: Array,
-    lam: Array,
-    alpha: Array,
-    prior,
-    mask: Optional[Array] = None,
-) -> Array:
-    """Eq 11 on a grid via the Pallas kernel (back-compat single-mode slice
-    of the fused kernel — see ``posterior_grid_alpha``)."""
-    if mask is None:
-        mask = jnp.ones_like(t)
-    return posterior_grid_pallas(
-        grid, t, f, mask, mu, lam, alpha, prior.a, prior.b,
-        mode="beta", interpret=_interpret(),
-    )
 
 
 def decode_attention(

@@ -244,63 +244,3 @@ def posterior_grid_fleet_pallas(
     )
     return jnp.stack([out_a[:, :g_n], out_b[:, :g_n]], axis=1)
 
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("mode", "block_g", "block_n", "interpret"),
-)
-def posterior_grid_pallas(
-    grid: Array,
-    t: Array,
-    f: Array,
-    mask: Array,
-    mu: Array,
-    lam: Array,
-    other_exp: Array,
-    prior_a: Array,
-    prior_b: Array,
-    *,
-    mode: str = "alpha",
-    block_g: int = DEFAULT_BLOCK_G,
-    block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = False,
-) -> Array:
-    """Single-unit, single-mode evaluation.  Returns (G,) f32.
-
-    Kept as a K=1 slice of the fused fleet kernel: ``other_exp`` is the held
-    exponent the requested mode consumes, the unused mode's inputs are
-    interior dummies and its output row is discarded.  Note the kernel body
-    is opaque to XLA, so the discarded mode IS computed — callers that need
-    both exponents should call ``posterior_grid_fleet_pallas`` once instead
-    of this entry twice (that is the whole point of the fusion); this slice
-    exists for validation and back-compat.
-    """
-    if mode not in ("alpha", "beta"):
-        raise ValueError(mode)
-    dummy = jnp.float32(0.5)
-    if mode == "alpha":
-        alpha, beta = dummy, other_exp
-        a_prior = (prior_a, prior_b)
-        b_prior = (jnp.float32(2.0), jnp.float32(2.0))
-    else:
-        alpha, beta = other_exp, dummy
-        a_prior = (jnp.float32(2.0), jnp.float32(2.0))
-        b_prior = (prior_a, prior_b)
-    out = posterior_grid_fleet_pallas(
-        grid,
-        t[None, :],
-        f[None, :],
-        mask[None, :],
-        mu,
-        lam,
-        alpha,
-        beta,
-        a_prior[0],
-        a_prior[1],
-        b_prior[0],
-        b_prior[1],
-        block_g=block_g,
-        block_n=block_n,
-        interpret=interpret,
-    )
-    return out[0, 0 if mode == "alpha" else 1]

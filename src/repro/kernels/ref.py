@@ -1,7 +1,8 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
+"""Pure-jnp oracles for the decode-attention and LRU-scan kernels.
 
 These are the ground truth the kernels are validated against (interpret=True
-on CPU, real lowering on TPU).  They are deliberately straightforward.
+on CPU, real lowering on TPU).  They are deliberately straightforward.  The
+posterior-grid kernel's oracle is ``repro.core.moments.log_posterior_grid``.
 """
 from __future__ import annotations
 
@@ -11,59 +12,6 @@ import jax
 import jax.numpy as jnp
 
 Array = jax.Array
-
-
-def posterior_grid_ref(
-    grid: Array,
-    t: Array,
-    f: Array,
-    mu: Array,
-    lam: Array,
-    other_exp: Array,
-    prior_a: Array,
-    prior_b: Array,
-    mask: Optional[Array] = None,
-    *,
-    mode: str = "alpha",
-) -> Array:
-    """Deprecated: unnormalized log-posterior of one scaling exponent.
-
-    mode="alpha": Eq 10 — grid is alpha, other_exp is the current beta.
-    mode="beta" : Eq 11 — grid is beta,  other_exp is the current alpha,
-                  including the -beta * sum(log f) Jacobian term.
-
-    Shapes: grid (G,), t/f/mask (N,) -> (G,).
-
-    The unified oracle lives in ``repro.core.moments.log_posterior_grid``
-    (fused both-modes, fleet-batched); this shim slices the requested mode
-    out of it for callers of the historical per-mode signature.
-    """
-    import warnings
-
-    warnings.warn(
-        "repro.kernels.ref.posterior_grid_ref is deprecated; use "
-        "repro.core.moments.log_posterior_grid (the fused both-modes fleet "
-        "oracle) or its per-mode slices "
-        "repro.core.moments.log_posterior_{alpha,beta}_ref.",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    if mode not in ("alpha", "beta"):
-        raise ValueError(mode)
-    from repro.core.moments import BetaParams, log_posterior_grid
-
-    prior = BetaParams(jnp.asarray(prior_a, jnp.float32), jnp.asarray(prior_b, jnp.float32))
-    dummy_prior = BetaParams.default()
-    dummy = jnp.asarray(0.5, jnp.float32)
-    if mode == "alpha":
-        both = log_posterior_grid(
-            grid, t, f, mu, lam, dummy, other_exp, prior, dummy_prior, mask
-        )
-        return both[..., 0, :]
-    both = log_posterior_grid(
-        grid, t, f, mu, lam, other_exp, dummy, dummy_prior, prior, mask
-    )
-    return both[..., 1, :]
 
 
 def decode_attention_ref(

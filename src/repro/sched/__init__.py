@@ -23,8 +23,8 @@ The full cycle — learn from telemetry, propose a split, score anomalies:
 (3,)
 
 ``Scheduler`` is the thin imperative shell (config + current state) used by
-the trainer/server loops; ``repro.core.HeterogeneityAwarePartitioner`` is the
-deprecated legacy wrapper delegating here.
+the trainer/server loops.  For two units, ``optimal_two_way_fraction`` picks
+the best split on the paper's (mean, variance) frontier for an ``Objective``.
 
 Multi-stage pipelines lift the same API to workflow DAGs (``repro.sched.dag``):
 
@@ -66,7 +66,7 @@ from .dag import (
 )
 from repro.core.sharding import ShardingConfig
 
-from .objectives import Objective, as_stage_objectives
+from .objectives import Objective, as_stage_objectives, optimal_two_way_fraction
 from .quantize import quantize_dag_fractions, quantize_fractions
 from .scheduler import (
     ProposeStats,
@@ -118,6 +118,7 @@ __all__ = [
     "num_workers",
     "observe",
     "observe_dag",
+    "optimal_two_way_fraction",
     "path_lengths",
     "propose",
     "propose_dag",

@@ -2,12 +2,10 @@
 
 The companion paper ("Partitioning Uncertain Workflows") frames the split
 choice as an objective over the (mean, variance) frontier.  One ``Objective``
-value now encodes that choice everywhere — the K-simplex optimizer
-(``sched.solve_fractions``), the two-way frontier sweep
-(``frontier.optimal_two_way_fraction``), microbatch quantization, and the
-serve path — replacing the three divergent encodings (``objective=`` strings,
-``risk_aversion=`` floats, hard-coded ``E + ra*Var``) that used to live in
-``frontier.py`` and ``partitioner.py``.
+value encodes that choice everywhere — the K-simplex optimizer
+(``sched.solve_fractions``), the two-way frontier pick
+(``optimal_two_way_fraction``, below), microbatch quantization, and the
+serve path.
 
 An ``Objective`` is a frozen, hashable dataclass, so it rides through
 ``jax.jit`` as a static argument; scores are pure jnp and differentiable
@@ -17,10 +15,20 @@ so the simplex optimizer can follow gradients).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.core.frontier import (
+    DEFAULT_QUAD_POINTS,
+    UnitParams,
+    completion_cdf,
+    mean_var_completion,
+    sweep_two_way,
+)
 
 Array = jax.Array
 
@@ -77,22 +85,6 @@ class Objective:
     @staticmethod
     def deadline_quantile(deadline: float) -> "Objective":
         return Objective(kind="deadline", deadline=float(deadline))
-
-    @staticmethod
-    def from_legacy(
-        objective: str,
-        risk_aversion: float = 0.0,
-        var_budget: float = math.inf,
-        deadline: float = 0.0,
-    ) -> "Objective":
-        """Map the old ``frontier.optimal_two_way_fraction`` string API."""
-        kind = {"constrained": "var_budget"}.get(objective, objective)
-        return Objective(
-            kind=kind,
-            risk_aversion=float(risk_aversion),
-            var_budget=float(var_budget),
-            deadline=float(deadline),
-        )
 
     # -- scoring -------------------------------------------------------------
     def score_moments(self, e_t: Array, var: Array, *, smooth: bool = False) -> Array:
@@ -153,8 +145,8 @@ def score_moments_dynamic(
     ``Objective.score_moments`` bakes its floats in as jit-static constants —
     right for the scheduler, whose objective rarely changes.  Callers that
     sweep the risk/budget parameter (e.g. tracing a tradeoff curve through
-    ``frontier.optimal_two_way_fraction``) use this form so only ``kind``
-    is static and every parameter value reuses one compilation.
+    ``optimal_two_way_fraction``) use this form so only ``kind`` is static
+    and every parameter value reuses one compilation.
     """
     if kind == "mean":
         return e_t
@@ -192,8 +184,6 @@ def evaluate(
     its own slice of a shared risk budget or end-to-end deadline without one
     compilation per stage (``sched.dag.propose_dag`` vmaps over stages).
     """
-    from repro.core.frontier import completion_cdf, mean_var_completion
-
     if objective.needs_cdf():
         d = objective.deadline if deadline is None else deadline
         p_meet = completion_cdf(jnp.asarray(d, fracs.dtype), fracs, params)
@@ -209,3 +199,49 @@ def evaluate(
         objective.var_budget if var_budget is None else var_budget,
         smooth=smooth,
     )
+
+
+def optimal_two_way_fraction(
+    params: UnitParams,
+    *,
+    objective: Objective = Objective.mean(),
+    num_f: int = 201,
+    num_points: int = DEFAULT_QUAD_POINTS,
+) -> Tuple[Array, Array, Array]:
+    """Pick f on the K=2 frontier: the best of ``num_f`` points for ``objective``.
+
+    Only the objective's *kind* is jit-static: its floats are passed traced,
+    so sweeping ``risk_aversion``, ``var_budget`` or ``deadline`` reuses one
+    compilation.  Returns (f*, mu(f*), sigma^2(f*)).
+    """
+    return _optimal_two_way(
+        params,
+        jnp.asarray(objective.risk_aversion, jnp.float32),
+        jnp.asarray(objective.var_budget, jnp.float32),
+        jnp.asarray(objective.deadline, jnp.float32),
+        kind=objective.kind,
+        num_f=num_f,
+        num_points=num_points,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("kind", "num_f", "num_points"))
+def _optimal_two_way(
+    params: UnitParams,
+    risk_aversion: Array,
+    var_budget: Array,
+    deadline: Array,
+    *,
+    kind: str,
+    num_f: int,
+    num_points: int,
+) -> Tuple[Array, Array, Array]:
+    f_grid, mu_f, var_f = sweep_two_way(params, num_f, num_points)
+    if kind == "deadline":
+        score = jax.vmap(
+            lambda f: -completion_cdf(deadline, jnp.stack([f, 1.0 - f]), params)
+        )(f_grid)
+    else:
+        score = score_moments_dynamic(kind, mu_f, var_f, risk_aversion, var_budget)
+    idx = jnp.argmin(score)
+    return f_grid[idx], mu_f[idx], var_f[idx]

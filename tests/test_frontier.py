@@ -2,14 +2,15 @@
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.frontier import (
     UnitParams,
     completion_cdf,
     mean_var_completion,
-    optimal_two_way_fraction,
     pareto_mask,
     sweep_two_way,
 )
+from repro.sched import Objective, optimal_two_way_fraction
 
 
 def test_cdf_is_product_of_unit_cdfs():
@@ -69,15 +70,15 @@ def test_paper_illustration_frontier():
 
 def test_objectives():
     p = UnitParams.of([30.0, 20.0], [2.0, 6.0])
-    f_mean, mu_m, var_m = optimal_two_way_fraction(p, objective="mean")
+    f_mean, mu_m, var_m = optimal_two_way_fraction(p, objective=Objective.mean())
     f_rav, mu_r, var_r = optimal_two_way_fraction(
-        p, objective="mean_var", risk_aversion=2.0
+        p, objective=Objective.mean_var(2.0)
     )
     # risk-averse point trades mean for variance
     assert float(var_r) <= float(var_m) + 1e-6
     assert float(mu_r) >= float(mu_m) - 1e-6
     f_con, mu_c, var_c = optimal_two_way_fraction(
-        p, objective="constrained", var_budget=float(var_m) * 0.5
+        p, objective=Objective.variance_budget(float(var_m) * 0.5)
     )
     assert float(var_c) <= float(var_m) * 0.5 + 1e-4
 
@@ -88,3 +89,20 @@ def test_scaling_exponents_shift_optimum():
     ideal = UnitParams.of([10.0, 10.0], [1.0, 1.0], [1.0, 1.0], [1.0, 1.0])
     f_i, _, _ = optimal_two_way_fraction(ideal)
     np.testing.assert_allclose(float(f_i), 0.5, atol=0.02)
+
+
+def test_two_way_objective_floats_trace_once():
+    """Only the objective's kind is static: three risk aversions share one
+    trace of the solve, and each still moves the pick along the frontier."""
+    obs.hook()
+    p = UnitParams.of([30.0, 20.0], [2.0, 6.0])
+    before = obs.snapshot()["traces"].get("_optimal_two_way", 0)
+    # num_f no other test uses, so the first call here traces
+    picks = [
+        optimal_two_way_fraction(p, objective=Objective.mean_var(ra), num_f=57)
+        for ra in (0.1, 1.0, 10.0)
+    ]
+    assert obs.snapshot()["traces"].get("_optimal_two_way", 0) - before == 1
+    variances = [float(v) for _, _, v in picks]
+    assert variances[0] >= variances[1] >= variances[2]
+    assert variances[0] > variances[2]

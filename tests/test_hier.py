@@ -6,8 +6,8 @@ The load-bearing claims:
      exactly on the pool, a mature worker keeps its own data;
   3. cold-start transfer: a hierarchically-admitted worker proposes
      near-fleet-mean in its first cycle and reaches its oracle fraction
-     in <= half the observations of a global-prior admit (the ISSUE's
-     acceptance scenario, also recorded as a BENCH_7 row);
+     in <= half the observations of a global-prior admit
+     (``test_cold_start_transfer_halves_observations`` asserts it);
   4. ``hierarchical=False`` admission is bitwise the legacy global-prior
      path, and the fixed-threshold serve gate never touches the new
      gate/hyperprior state;

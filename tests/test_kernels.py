@@ -6,13 +6,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.moments import BetaParams, log_posterior_grid
+from repro.core.moments import (
+    BetaParams,
+    log_posterior_alpha_ref,
+    log_posterior_beta_ref,
+    log_posterior_grid,
+)
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention_pallas
-from repro.kernels.posterior_grid import (
-    posterior_grid_fleet_pallas,
-    posterior_grid_pallas,
-)
+from repro.kernels.posterior_grid import posterior_grid_fleet_pallas
 
 
 def _fleet_case(k, n, seed=0, zero_cols=False):
@@ -101,28 +103,6 @@ def test_posterior_grid_fleet_matches_vmapped_oracle():
     _assert_logp_close(got, want)
 
 
-def test_posterior_grid_single_unit_is_fleet_slice():
-    """The legacy single-unit, single-mode entry == the matching row of the
-    fused fleet launch with K=1."""
-    g, n = 128, 300
-    t, f, mask, mu, lam, alpha, beta, ap, bp = _fleet_case(1, n, seed=5)
-    grid = jnp.linspace(1e-4, 1 - 1e-4, g, dtype=jnp.float32)
-    fleet = posterior_grid_fleet_pallas(
-        grid, t, f, mask, mu, lam, alpha, beta, ap.a, ap.b, bp.a, bp.b,
-        interpret=True,
-    )
-    got_a = posterior_grid_pallas(
-        grid, t[0], f[0], mask[0], mu[0], lam[0], beta[0], ap.a[0], ap.b[0],
-        mode="alpha", interpret=True,
-    )
-    got_b = posterior_grid_pallas(
-        grid, t[0], f[0], mask[0], mu[0], lam[0], alpha[0], bp.a[0], bp.b[0],
-        mode="beta", interpret=True,
-    )
-    _assert_logp_close(got_a, fleet[0, 0], rtol=1e-6)
-    _assert_logp_close(got_b, fleet[0, 1], rtol=1e-6)
-
-
 def test_posterior_grid_fleet_fully_masked_worker():
     """A worker with zero valid observations must fall back to its prior
     (finite everywhere, no NaN/Inf from the dead telemetry)."""
@@ -192,21 +172,24 @@ def test_posterior_grid_fleet_counts_its_padding():
 @pytest.mark.parametrize("mode", ["alpha", "beta"])
 @pytest.mark.parametrize("g,n", [(64, 100), (300, 777), (512, 2048), (17, 33)])
 def test_posterior_grid_shapes(mode, g, n):
+    """One worker (K = 1) against the oracle's single-mode slice: row 0 of
+    the launch is the alpha posterior, row 1 the beta posterior."""
     key = jax.random.PRNGKey(g * 1000 + n)
     kf, kt = jax.random.split(key)
     f = jax.random.uniform(kf, (n,), minval=0.05, maxval=0.95)
     t = f**0.9 * 25.0 + f**0.7 * 2.0 * jax.random.normal(kt, (n,))
     grid = jnp.linspace(1e-4, 1 - 1e-4, g, dtype=jnp.float32)
     mask = (jnp.arange(n) % 7 != 0).astype(jnp.float32)
-    args = (jnp.float32(25.0), jnp.float32(0.25), jnp.float32(0.7),
-            jnp.float32(2.0), jnp.float32(3.0))
-    got = posterior_grid_pallas(
-        grid, t, f, mask, *args, mode=mode, interpret=True,
-        block_g=128, block_n=256,
-    )
-    want = ref.posterior_grid_ref(
-        grid, t, f, args[0], args[1], args[2], args[3], args[4], mask, mode=mode
-    )
+    mu, lam, other = jnp.float32(25.0), jnp.float32(0.25), jnp.float32(0.7)
+    prior = BetaParams(jnp.float32(2.0), jnp.float32(3.0))
+    # Each mode consumes the other exponent: both are 0.7, both priors (2, 3).
+    got = posterior_grid_fleet_pallas(
+        grid, t[None], f[None], mask[None], mu, lam, other, other,
+        prior.a, prior.b, prior.a, prior.b,
+        interpret=True, block_g=128, block_n=256,
+    )[0, 0 if mode == "alpha" else 1]
+    oracle = log_posterior_alpha_ref if mode == "alpha" else log_posterior_beta_ref
+    want = oracle(grid, t, f, mu, lam, other, prior, mask)
     scale = 1.0 + float(jnp.max(jnp.abs(want)))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5 * scale)
@@ -223,13 +206,14 @@ def test_posterior_grid_block_invariance(block_g, block_n):
     t = f * 10.0 + jax.random.normal(kt, (n,))
     grid = jnp.linspace(1e-4, 1 - 1e-4, g, dtype=jnp.float32)
     mask = jnp.ones((n,), jnp.float32)
-    out = posterior_grid_pallas(
-        grid, t, f, mask, 10.0, 1.0, 0.9, 2.0, 2.0,
-        mode="alpha", interpret=True, block_g=block_g, block_n=block_n,
-    )
-    want = ref.posterior_grid_ref(
+    out = posterior_grid_fleet_pallas(
+        grid, t[None], f[None], mask[None], 10.0, 1.0, 0.5, 0.9,
+        2.0, 2.0, 2.0, 2.0,
+        interpret=True, block_g=block_g, block_n=block_n,
+    )[0, 0]
+    want = log_posterior_alpha_ref(
         grid, t, f, jnp.float32(10.0), jnp.float32(1.0), jnp.float32(0.9),
-        jnp.float32(2.0), jnp.float32(2.0), mask, mode="alpha",
+        BetaParams(jnp.float32(2.0), jnp.float32(2.0)), mask,
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-4, atol=1e-3)
 
@@ -245,28 +229,6 @@ def test_posterior_grid_fleet_rejects_unaligned_blocks(block_g, block_n):
             grid, t, f, mask, mu, lam, alpha, beta, ap.a, ap.b, bp.a, bp.b,
             interpret=True, block_g=block_g, block_n=block_n,
         )
-
-
-def test_posterior_grid_ref_deprecation_names_unified_oracle():
-    """The shim's DeprecationWarning must point callers at the CURRENT
-    replacement — ``repro.core.moments.log_posterior_grid`` — and the
-    equivalence the message promises must actually hold."""
-    grid = jnp.linspace(1e-4, 1 - 1e-4, 8, dtype=jnp.float32)
-    t = jnp.asarray([1.0, 2.0, 3.0, 4.0], jnp.float32)
-    f = jnp.full((4,), 0.5, jnp.float32)
-    args = (jnp.float32(1.0), jnp.float32(1.0), jnp.float32(0.5),
-            jnp.float32(2.0), jnp.float32(2.0))
-    with pytest.warns(
-        DeprecationWarning, match=r"repro\.core\.moments\.log_posterior_grid"
-    ) as rec:
-        out = ref.posterior_grid_ref(grid, t, f, *args, mode="alpha")
-    assert "log_posterior_{alpha,beta}_ref" in str(rec[0].message)
-    from repro.core.moments import log_posterior_alpha_ref
-
-    want = log_posterior_alpha_ref(
-        grid, t, f, args[0], args[1], args[2], BetaParams(args[3], args[4])
-    )
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-6)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

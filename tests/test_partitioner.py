@@ -1,24 +1,18 @@
-"""K-way partitioner: frontier optimization, quantization, online API."""
+"""K-way partitioner: frontier optimization on the simplex and elastic fleets."""
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
+from repro import sched
 from repro.core.frontier import UnitParams, mean_var_completion
-from repro.core.partitioner import (
-    HeterogeneityAwarePartitioner,
-    WorkerTelemetry,
-    optimize_fractions,
-    quantize_fractions,
-)
 
 
 def test_faster_worker_gets_more_work():
     p = UnitParams.of([10.0, 30.0], [1.0, 1.0])
-    fr, e, v = optimize_fractions(p)
+    fr, stats = sched.solve_fractions(p, objective=sched.Objective.mean(), steps=300)
     assert float(fr[0]) > float(fr[1])  # unit 0 is 3x faster
     # beats equal split
     e_eq, _ = mean_var_completion(jnp.asarray([0.5, 0.5]), p)
-    assert float(e) < float(e_eq)
+    assert float(stats.e_t) < float(e_eq)
 
 
 def test_optimizer_near_closed_form_linear_case():
@@ -26,56 +20,13 @@ def test_optimizer_near_closed_form_linear_case():
     K linear units equalizes f_k * mu_k -> f_k proportional to 1/mu_k."""
     mus = [8.0, 16.0, 32.0]
     p = UnitParams.of(mus, [0.01, 0.01, 0.01])
-    fr, _, _ = optimize_fractions(p)
+    fr, _ = sched.solve_fractions(p, objective=sched.Objective.mean(), steps=300)
     inv = np.array([1 / m for m in mus])
     np.testing.assert_allclose(np.asarray(fr), inv / inv.sum(), atol=0.02)
 
 
-def test_quantize_sums_and_bounds():
-    fr = np.array([0.61, 0.29, 0.10])
-    counts = quantize_fractions(fr, 16)
-    assert counts.sum() == 16
-    assert (counts >= 1).all()
-    assert counts[0] > counts[1] > counts[2]
-
-
-def test_quantize_refinement_improves_objective():
-    p = UnitParams.of([10.0, 20.0, 40.0], [1.0, 2.0, 4.0])
-    fr, _, _ = optimize_fractions(p)
-    counts = quantize_fractions(np.asarray(fr), 8, p)
-    naive = np.array([3, 3, 2])
-
-    def obj(c):
-        e, _ = mean_var_completion(jnp.asarray(c / 8.0, jnp.float32), p)
-        return float(e)
-
-    assert obj(counts) <= obj(naive) + 1e-6
-
-
-@pytest.mark.slow
-def test_online_partitioner_learns_and_rebalances():
-    # Slow: 6 full observe rounds through the DEPRECATED wrapper; the same
-    # scenario stays tier-1 through the pure API
-    # (test_sched.py::test_online_learning_rebalances_functional).
-    rng = np.random.default_rng(0)
-    true_mu = np.array([5.0, 20.0])  # worker 0 is 4x faster
-    part = HeterogeneityAwarePartitioner(2, seed=0, n_iters=10, grid_size=128,
-                                         mu_guess=10.0)
-    for _ in range(6):
-        fracs = np.tile(part.propose_fractions()[0][:, None], (1, 32))
-        times = np.stack([
-            np.maximum(f**0.9 * m + 0.5 * rng.normal(size=32), 1e-3)
-            for f, m in zip(fracs, true_mu)
-        ])
-        part.observe(WorkerTelemetry(jnp.asarray(fracs), jnp.asarray(times)))
-    fr, e, v = part.propose_fractions()
-    assert fr[0] > 0.6  # the fast worker carries most of the load
-    counts = part.propose_microbatches(8)
-    assert counts.sum() == 8 and counts[0] > counts[1]
-
-
 def test_elastic_add_remove():
-    part = HeterogeneityAwarePartitioner(4, seed=1)
+    part = sched.Scheduler(4, seed=1)
     part.remove_workers(np.array([False, True, False, False]))
     assert part.num_workers == 3
     part.add_workers(2)

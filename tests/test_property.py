@@ -11,8 +11,8 @@ from hypothesis import given, settings
 
 from repro.core.frontier import UnitParams, completion_cdf, pareto_mask
 from repro.core.moments import fit_beta_method_of_moments
-from repro.core.partitioner import quantize_fractions
 from repro.core.posterior import NormalGammaParams, update_normal_gamma
+from repro.sched import quantize_fractions
 from repro.train.train_step import cross_entropy
 
 hypothesis.settings.register_profile(

@@ -12,10 +12,13 @@ def _check_invariants(counts, total, min_per_worker=1):
     assert (counts >= min_per_worker).all()
 
 
-def test_counts_sum_and_floor():
-    counts = sched.quantize_fractions(np.array([0.61, 0.29, 0.10]), 16)
-    _check_invariants(counts, 16)
-    assert counts[0] > counts[1] > counts[2]
+@pytest.mark.parametrize(
+    "fracs,total", [([0.61, 0.29, 0.10], 16), ([0.4, 0.3, 0.2, 0.1], 32)]
+)
+def test_counts_sum_and_floor(fracs, total):
+    counts = sched.quantize_fractions(np.array(fracs), total)
+    _check_invariants(counts, total)
+    assert (np.diff(counts) < 0).all()  # the fraction order survives
 
 
 def test_min_per_worker_respected():
@@ -69,8 +72,12 @@ def test_total_too_small_raises():
         sched.quantize_fractions(np.array([0.5, 0.5]), 3, min_per_worker=2)
 
 
-def test_batched_refinement_improves_objective():
-    p = UnitParams.of([10.0, 20.0, 40.0], [1.0, 2.0, 4.0])
+@pytest.mark.parametrize(
+    "mu,sigma", [([10.0, 20.0, 40.0], [1.0, 2.0, 4.0]),
+                 ([10.0, 40.0, 40.0], [1.0, 4.0, 4.0])]
+)
+def test_batched_refinement_improves_objective(mu, sigma):
+    p = UnitParams.of(mu, sigma)
     fracs, _ = sched.solve_fractions(p)
     counts = sched.quantize_fractions(np.asarray(fracs), 8, p)
     _check_invariants(counts, 8)

@@ -6,6 +6,7 @@ fixture under ``tools/reprolint/testdata/<rule>/``; this module asserts the
 TP is flagged by exactly that rule and the FP produces *zero* findings, so
 both the detection and the precision of each rule are pinned.
 """
+import ast
 import json
 import subprocess
 import sys
@@ -201,16 +202,38 @@ def test_baseline_rejects_unknown_version(tmp_path):
 # ------------------------------------------------------------------- layer map
 def test_layer_map_flags_upward_and_allows_downward():
     layer_map = LayerMap.load()
-    up = layer_map.violation("repro.core.partitioner", "repro.sched.scheduler")
+    up = layer_map.violation("repro.core.frontier", "repro.sched.objectives")
     assert up is not None and "upward import" in up
-    assert layer_map.violation("repro.sched.compat", "repro.core.frontier") is None
+    assert layer_map.violation("repro.sched.objectives", "repro.core.frontier") is None
     assert layer_map.violation("repro.serve.service", "repro.hier.pool") is None
 
 
+def test_core_never_imports_sched():
+    """RL005 exempts deferred in-function imports; the core layer takes no
+    such exemption for sched: no module under `src/repro/core` imports
+    `repro.sched`, at module level or inside a function."""
+    found = []
+    for path in sorted((ROOT / "src" / "repro" / "core").rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text(), str(path))):
+            if isinstance(node, ast.Import):
+                names = [alias.name for alias in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                parts = [node.module] if node.module else []
+                if node.level:  # relative: resolve against the module's package
+                    pkg = ["repro", *path.relative_to(ROOT / "src" / "repro").parts[:-1]]
+                    parts = pkg[: len(pkg) - node.level + 1] + parts
+                module = ".".join(parts)
+                names = [module] + [f"{module}.{a.name}" for a in node.names]
+            else:
+                continue
+            found += [f"{path.name}:{node.lineno} {name}" for name in names
+                      if name == "repro.sched" or name.startswith("repro.sched.")]
+    assert found == []
+
+
 def test_importing_core_does_not_import_sched():
-    """The RL005 fix in the flesh: the legacy partitioner wrapper moved to
-    `repro.sched.compat`, so importing the core layer must no longer pull
-    the sched layer into the process (the PEP 562 shim defers it)."""
+    """Importing the core layer must not pull the sched layer into the
+    process."""
     proc = subprocess.run(
         [sys.executable, "-c",
          "import sys, repro.core; "
@@ -241,7 +264,7 @@ def test_shipped_tree_lints_clean():
     baseline.  A finding here means a new invariant violation landed."""
     proc = subprocess.run(
         [sys.executable, "-m", "tools.reprolint",
-         "src", "tests", "benchmarks", "--format=json"],
+         "src", "tests", "--format=json"],
         cwd=ROOT, capture_output=True, text=True, timeout=300,
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr

@@ -16,7 +16,7 @@ RL006     PRNG key consumed twice without an intervening ``split``
 RL007     Python ``if``/``while`` on a traced value
 ========  ==============================================================
 
-Run ``python -m tools.reprolint src tests benchmarks``; see
+Run ``python -m tools.reprolint src tests``; see
 ``docs/static-analysis.md`` for rule rationale, suppression syntax and the
 recipe for adding a rule.
 """

@@ -2,7 +2,7 @@
 
 Usage::
 
-    python -m tools.reprolint src tests benchmarks
+    python -m tools.reprolint src tests
     python -m tools.reprolint src --format=json
     python -m tools.reprolint src --baseline tools/reprolint/baseline.json
     python -m tools.reprolint --write-baseline tools/reprolint/baseline.json src

@@ -7,9 +7,9 @@ cannot create import cycles and are the repo's sanctioned escape hatch for
 acyclic back-references (``kernels/ops.py``'s duck-typed ShardingConfig
 import, ``sched``'s lazy hierarchical path).
 
-The rule caught ``repro.core.partitioner`` importing ``repro.sched`` at
-module level (core -> sched is upward); the legacy wrapper now lives in
-``repro.sched.compat`` with a lazy PEP 562 shim left behind.
+The rule caught a legacy partitioner wrapper in ``repro.core`` importing
+``repro.sched`` at module level (core -> sched is upward); the wrapper has
+since been deleted.
 """
 from __future__ import annotations
 
@@ -24,7 +24,7 @@ from . import Rule
 
 
 def _module_name_for_path(path: str, root_package: str):
-    """``src/repro/core/partitioner.py`` -> (``repro.core.partitioner``, False);
+    """``src/repro/core/frontier.py`` -> (``repro.core.frontier``, False);
     an ``__init__.py`` maps to its package name with ``is_package=True``."""
     parts = list(PurePosixPath(path.replace("\\", "/")).parts)
     if root_package not in parts:
